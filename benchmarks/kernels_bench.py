@@ -20,7 +20,6 @@ from repro.store import DatasetStore
 from repro.tuning import (
     AutotuneCache,
     autotune_knn,
-    probe_pallas_capability,
     set_default_cache,
 )
 
@@ -57,15 +56,8 @@ def run(quick: bool = False) -> None:
     x = rng.standard_normal((n, d)).astype(np.float32)
     q = rng.standard_normal((M, d)).astype(np.float32)
 
-    # probe + persist the compile-capability verdict, then plan this
-    # section's fused rows against a view WITHOUT the verdict: this bench
-    # measures the Pallas executors on purpose (interpret mode included),
-    # while serving planners on the same host honor the persisted veto.
     real_cache = AutotuneCache.for_device()
-    verdict = probe_pallas_capability(cache=real_cache)
-    emit("kernels/pallas_capability", 0.0, f"compiled={verdict}",
-         compiled=bool(verdict))
-    set_default_cache(real_cache.without_capability())
+    set_default_cache(real_cache)
     try:
         _run_rows(quick, n, d, x, q, real_cache)
     finally:
@@ -126,8 +118,7 @@ def _run_rows(quick, n, d, x, q, real_cache) -> None:
     # persisted device cache would silently make this tuned-vs-tuned and
     # hide autotune regressions); the sweep writes to the real per-device
     # cache so CI machines accumulate warm starts, and the tuned row plans
-    # against a fresh capability-free view of it (fused rows must still
-    # plan Pallas here even when the persisted verdict is False).
+    # against it.
     set_default_cache(AutotuneCache(path=None))
     fresh = ExactKNN(k=K, backend="pallas").fit(x)
     p_cold = fresh.plan_for("fqsd", M)
@@ -144,7 +135,7 @@ def _run_rows(quick, n, d, x, q, real_cache) -> None:
         cache=real_cache, repeats=1 if quick else 2,
         max_candidates=4 if quick else None,
     )
-    set_default_cache(real_cache.without_capability())
+    set_default_cache(real_cache)
     tuned_eng = ExactKNN(k=K, backend="pallas").fit(x)
     p_tuned = tuned_eng.plan_for("fqsd", M)
     t = time_samples(tuned_eng.query_batch, q, repeats=REPEATS)

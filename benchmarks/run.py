@@ -43,6 +43,9 @@ def main(argv=None) -> int:
                          "(CI uploads <json-dir>/BENCH_kernels.json)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         chipknn,
         common,

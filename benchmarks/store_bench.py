@@ -30,17 +30,15 @@ ISSUE 7 adds the mesh subsection: the same tier pair on a device group —
 resident row-sharded int8 (fdsq-sharded-int8) and the out-of-core ring
 stream (fqsd-sharded-int8-streamed) — reporting qps, p50, per-device scan
 bytes, ``bytes_ratio_vs_f32``, and modeled joules/query (device TDP x
-group size from ``repro.roofline.hw``; a proxy, labeled as such — first
-cut of the ROADMAP's energy-per-query item). A single-device run (the
-default CI bench step) re-executes this module in a forced-4-device
-subprocess and merges its rows, so the mesh trajectory rides the same
->20% regression gate as every other store row.
+group size from ``repro.roofline.hw``; a proxy, labeled as such, and
+left out on a device kind the peaks table does not list). The mesh rows
+run in-process when the process has two or more devices: a multi-chip
+host, or CPU devices forced with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``. With one device
+the bench says they were not run.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
 import tempfile
 
@@ -54,8 +52,6 @@ from repro.store import DatasetStore
 
 K = 10
 REPEATS = 7
-MESH_DEVICES = 4
-_MESH_ROW_PREFIX = "MESH_ROW "
 
 
 def _pcts(times: list[float], m: int) -> tuple[float, float, float]:
@@ -218,72 +214,60 @@ def run(quick: bool = False) -> None:
 
 
 def _mesh_section(quick: bool) -> None:
-    """Run the mesh rows in-process when this host already has a device
-    group, else re-exec this module in a forced-4-device subprocess (XLA's
-    device count is locked at first jax init) and merge its rows."""
+    """Run the mesh rows in this process when it has a device group. One
+    process per chip: no child is started, since a parent that has touched
+    JAX holds the chip a child would need."""
     import jax
 
-    if len(jax.devices()) > 1:
-        _run_mesh(quick)
+    n_dev = len(jax.devices())
+    if n_dev < 2:
+        print(f"# store_bench: mesh rows not run: this process has {n_dev} "
+              "device (they need >= 2)", file=sys.stderr)
         return
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={MESH_DEVICES}"
-    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, "-m", "benchmarks.store_bench", "--mesh"]
-    if quick:
-        cmd.append("--quick")
-    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=1800,
-                          env=env,
-                          cwd=os.path.dirname(os.path.dirname(__file__)))
-    if proc.returncode != 0:
-        # observability must not break the bench run — but say so loudly
-        # instead of silently dropping the mesh rows
-        print("store_bench: mesh subsection SKIPPED (subprocess failed):\n"
-              + proc.stderr[-2000:], file=sys.stderr)
-        return
-    for line in proc.stdout.splitlines():
-        if line.startswith(_MESH_ROW_PREFIX):
-            row = json.loads(line[len(_MESH_ROW_PREFIX):])
-            emit(row.pop("name"), row.pop("us_per_call"),
-                 row.pop("derived", ""), **row)
+    _run_mesh(quick)
 
 
 def _run_mesh(quick: bool) -> None:
     """The mesh rows proper; requires >1 jax device in this process."""
     import jax
 
-    from repro import compat
-    from repro.roofline.hw import TPU_V5E
+    from repro.roofline.hw import peaks_for
 
     n, d, m = (32768, 128, 16) if quick else (131072, 128, 64)
+    dev = jax.devices()[0]
     n_dev = len(jax.devices())
-    mesh = compat.make_mesh((n_dev,), ("data",))
+    mesh = jax.make_mesh((n_dev,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     # modeled energy: wall time x (device TDP x group size); a proxy, not a
-    # measurement — see benchmarks/common.py
-    watts = TPU_V5E.tdp_watts * n_dev
-    energy_model = f"{TPU_V5E.name}_tdp_x{n_dev}"
+    # measurement — see benchmarks/common.py. TPU rows only, from the
+    # table keyed by device kind: an unlisted TPU is an error, and no
+    # chip's TDP stands in for another (or for the CPU's).
+    spec = peaks_for(dev.device_kind) if dev.platform == "tpu" else None
+
+    def energy_fields(qps: float) -> dict:
+        if spec is None:
+            return {}
+        return {"joules_per_query": energy_j(1.0, spec.tdp_watts * n_dev) / qps,
+                "energy_model": f"{spec.name}_tdp_x{n_dev}"}
     rng = np.random.default_rng(0)
     x = rng.standard_normal((n, d)).astype(np.float32)
     q = rng.standard_normal((m, d)).astype(np.float32)
     repeats = max(2, REPEATS // 2)
 
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         # resident row-sharded certified int8 (fdsq-sharded-int8)
         eng = ExactKNN(k=K, mesh=mesh, mesh_axes=("data",))
         store = DatasetStore.from_array(x, row_mult=eng._row_mult(n),
                                         tiers=("f32", "int8"))
         eng.fit_store(store)
         p50, p99, qps, nbytes, cert, res = _bench(eng, q, "int8", repeats)
-        jpq = energy_j(1.0, watts) / qps  # watts / (queries/s) = J per query
         per_dev = res.stats["bytes_per_device"]
         emit("store/mesh_int8_resident", p50,
-             f"qps={qps:.0f};certified={cert:.3f};devs={n_dev};"
-             f"J/q={jpq:.2e}",
+             f"qps={qps:.0f};certified={cert:.3f};devs={n_dev}",
              tier="int8", residency="mesh-resident", qps=qps, p50_us=p50,
              p99_us=p99, bytes_scanned=nbytes, bytes_per_device=per_dev,
-             certified_exact=cert, n_devices=n_dev, joules_per_query=jpq,
-             energy_model=energy_model, n=n, d=d, m=m, k=K)
+             certified_exact=cert, n_devices=n_dev, n=n, d=d, m=m, k=K,
+             **energy_fields(qps))
 
         # out-of-core ring stream (fqsd-sharded-int8-streamed): one store,
         # shard i scans on device i mod P, nothing resident
@@ -297,26 +281,16 @@ def _run_mesh(quick: bool) -> None:
                                                         repeats)
             per_dev = res.stats["bytes_per_device"]
             ratio = sum(per_dev) / store.nbytes("f32")
-            jpq = energy_j(1.0, watts) / qps
             emit("store/mesh_int8_ring_streamed", p50,
                  f"qps={qps:.0f};certified={cert:.3f};devs={n_dev};"
-                 f"bytes={ratio:.2f}x_f32;J/q={jpq:.2e}",
+                 f"bytes={ratio:.2f}x_f32",
                  tier="int8", residency="mesh-ring-streamed", qps=qps,
                  p50_us=p50, p99_us=p99, bytes_scanned=i8_bytes,
                  bytes_per_device=per_dev, bytes_ratio_vs_f32=ratio,
                  certified_exact=cert, n_devices=n_dev,
-                 joules_per_query=jpq, energy_model=energy_model,
                  n_shards=store.n_shards, n=n, d=d, m=m, k=K,
-                 **_phase_fields(res))
+                 **_phase_fields(res), **energy_fields(qps))
 
 
 if __name__ == "__main__":
-    # subprocess entry for the mesh subsection (see _mesh_section): emits
-    # the usual CSV rows plus one machine-readable MESH_ROW line per row
-    # for the parent process to merge into its RESULTS
-    if "--mesh" in sys.argv[1:]:
-        _run_mesh(quick="--quick" in sys.argv[1:])
-        for _row in RESULTS.get("store", []):
-            print(_MESH_ROW_PREFIX + json.dumps(_row), flush=True)
-    else:
-        run(quick="--quick" in sys.argv[1:])
+    run(quick="--quick" in sys.argv[1:])

@@ -105,8 +105,6 @@ class Router:
         """
         import jax
 
-        from repro import compat
-
         if isinstance(devices, int):
             avail = jax.devices()
             if not 1 <= devices <= len(avail):
@@ -119,8 +117,9 @@ class Router:
             raise ValueError("`devices` must name at least one device")
         group = tuple(d.id for d in devices)
         if group not in _MESH_BY_GROUP:
-            _MESH_BY_GROUP[group] = compat.make_mesh(
-                (len(devices),), ("data",), devices=devices
+            _MESH_BY_GROUP[group] = jax.make_mesh(
+                (len(devices),), ("data",),
+                axis_types=(jax.sharding.AxisType.Auto,), devices=devices,
             )
         return _MESH_BY_GROUP[group]
 

@@ -27,6 +27,13 @@ Metric = Literal["l2", "ip", "cos"]
 
 _SUPPORTED: tuple[str, ...] = ("l2", "ip", "cos")
 
+#: Precision of every f32 distance contraction. On a TPU the default
+#: precision rounds f32 operands to bf16 (about 2^-8 relative error) before
+#: the MXU; HIGHEST keeps the products f32-class, which the exact top-k and
+#: the int8 certificate (repro.core.quantized) both assume. Pallas lowers
+#: it to Mosaic's fp32 contract precision inside the fused kernels.
+EXACT_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def validate_metric(metric: str) -> None:
     if metric not in _SUPPORTED:
@@ -49,11 +56,13 @@ def l2_sq(q: jax.Array, x: jax.Array, x_norms: jax.Array | None = None) -> jax.A
     qn = jnp.sum(q32 * q32, axis=-1, keepdims=True)  # (M, 1)
     xn = row_norms_sq(x) if x_norms is None else x_norms.astype(jnp.float32)
     # -2 <q, x> : contraction in f32 (preferred_element_type pins the MXU
-    # accumulator width on TPU).
+    # accumulator width on TPU; HIGHEST keeps f32 inputs from being rounded
+    # through one bf16 pass, which would misorder near-ties).
     cross = jax.lax.dot_general(
         q, x,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=EXACT_PRECISION,
     )
     d = qn - 2.0 * cross + xn[None, :]
     # Guard tiny negatives from cancellation; distances are mathematically >= 0.
@@ -66,6 +75,7 @@ def inner_product(q: jax.Array, x: jax.Array) -> jax.Array:
         q, x,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=EXACT_PRECISION,
     )
 
 

@@ -296,8 +296,16 @@ class ExactKNN:
         self._seen_mutations = store.mutation_count
         if self._resident:
             host = store.resident()  # tombstones already folded into norms
-            vec = jnp.asarray(host.vectors, dtype=self.dtype)
-            nrm = jnp.asarray(host.norms)
+            if self.mesh is not None:
+                # straight from host to each device's rows: staging the
+                # whole corpus on one device first would not fit once the
+                # corpus is larger than one chip
+                vec, nrm = sh.shard_dataset(
+                    self.mesh, np.asarray(host.vectors, dtype=self.dtype),
+                    np.asarray(host.norms), self.mesh_axes)
+            else:
+                vec = jnp.asarray(host.vectors, dtype=self.dtype)
+                nrm = jnp.asarray(host.norms)
             if self._cos_prenormalized:
                 # cos is scale-invariant, so the resident view is normalized
                 # ONCE here instead of per query batch inside the fused
@@ -310,8 +318,6 @@ class ExactKNN:
                     jnp.isfinite(rn) & (rn > 0),
                     vec / jnp.maximum(rn, 1e-30), 0.0,
                 ).astype(self.dtype)
-            if self.mesh is not None:
-                vec, nrm = sh.shard_dataset(self.mesh, vec, nrm, self.mesh_axes)
             self._ds = part.PaddedDataset(vec, nrm, host.n_valid, 0)
             if store.has_tier("int8") and self.metric == "l2":
                 self._refresh_int8_view()

@@ -230,6 +230,16 @@ def cache_info() -> dict:
     }
 
 
+def compiled_texts(executor: str) -> list[str]:
+    """HLO text of every cached compiled executable of `executor` (built
+    by ``.lower().compile()``). A fused executor compiled for a TPU shows
+    its Mosaic kernel as a ``tpu_custom_call``; in interpret mode the
+    kernel is plain HLO and no such call appears."""
+    return [fn.as_text() for key, fn in _EXECUTABLE_CACHE.items()
+            if isinstance(key[0], tuple) and key[0][:1] == (executor,)
+            and hasattr(fn, "as_text")]
+
+
 def clear_executable_cache() -> None:
     _EXECUTABLE_CACHE.clear()
     _CACHE_STATS["hits"] = _CACHE_STATS["misses"] = 0
@@ -785,13 +795,25 @@ def _fdsq_sharded(plan, queries, dataset: part.PaddedDataset, ctx) -> TopK:
 
 @register_executor("fqsd-sharded")
 def _fqsd_sharded(plan, queries, dataset: part.PaddedDataset, ctx) -> TopK:
-    """Mesh-distributed FQ-SD via the compute/comm-overlapped ring (the
-    fully-partitioned layout — see repro.core.sharded.fqsd_ring)."""
+    """Mesh-distributed FQ-SD via the query-direction ring: the dataset
+    rows stay on their device and the (query block, queue) pairs rotate
+    (see repro.core.sharded.fqsd_ring_queries)."""
     if ctx.mesh is None:
         raise ValueError("plan requires a mesh but ExecContext.mesh is None")
-    key = (plan.cache_key(), ctx.mesh)
-    fn = _cached(key, lambda: sh.fqsd_ring(ctx.mesh, plan.k, plan.metric))
-    return fn(queries, dataset.vectors, dataset.norms)
+    axes = tuple(ctx.mesh_axes)
+    query_axis = axes[0]
+    model_axis = axes[1] if len(axes) > 1 else None
+    key = (plan.cache_key(), ctx.mesh, axes)
+    fn = _cached(key, lambda: sh.fqsd_ring_queries(
+        ctx.mesh, plan.k, plan.metric, query_axis, model_axis))
+    # queries shard over the ring axis: pad the batch to a multiple of it
+    m = int(queries.shape[0])
+    ring = int(ctx.mesh.shape[query_axis])
+    pad = -m % ring
+    if pad:
+        queries = jnp.pad(queries, ((0, pad), (0, 0)))
+    out = fn(queries, dataset.vectors, dataset.norms)
+    return TopK(out.scores[:m], out.indices[:m])
 
 
 @register_executor("fdsq-sharded-int8")
@@ -871,6 +893,12 @@ def _int8_mesh_streamed(plan, queries, store, ctx) -> TopK:
     li0 = np.full((m, r + 1), -1, np.int32)
     lbs = [jax.device_put(lb0, d) for d in devices]
     lis = [jax.device_put(li0, d) for d in devices]
+    # each device's step runs under a one-device mesh of its own: inside an
+    # ambient jax.set_mesh over the whole group, jit refuses arguments
+    # committed to a single device of it (incompatible devices), and
+    # without one the scope is a no-op
+    dev_scopes = [jax.sharding.Mesh(np.array([dev]), ("data",))
+                  for dev in devices]
     ring = make_ring_put(devices)
     # prefetch at least one shard per device so the ring never starves
     stream = DoubleBufferedStream(
@@ -894,14 +922,16 @@ def _int8_mesh_streamed(plan, queries, store, ctx) -> TopK:
             if direct_r1 is None:
                 direct_r1 = _cached(("direct-step", r + 1),
                                     lambda: make_direct_partition_step(r + 1))
-            lbs[d], lis[d] = direct_r1(lbs[d], lis[d], qs[d], p.vectors,
-                                       p.norms, jnp.int32(p.base_index))
+            with jax.set_mesh(dev_scopes[d]):
+                lbs[d], lis[d] = direct_r1(lbs[d], lis[d], qs[d], p.vectors,
+                                           p.norms, jnp.int32(p.base_index))
             dev_bytes[d] += (int(p.vectors.shape[0])
                              * int(p.vectors.shape[1]) * 4)
         else:
-            lbs[d], lis[d] = bound_step(lbs[d], lis[d], qs[d], p.q,
-                                        p.scales, p.err, p.qnorm,
-                                        jnp.int32(p.base_index))
+            with jax.set_mesh(dev_scopes[d]):
+                lbs[d], lis[d] = bound_step(lbs[d], lis[d], qs[d], p.q,
+                                            p.scales, p.err, p.qnorm,
+                                            jnp.int32(p.base_index))
             dev_bytes[d] += p.scan_bytes()
         shard_i += 1
     ctx.stream_stats = {"transfers": stream.transfers,

@@ -27,16 +27,9 @@ the same compiled executable forever after.
 from __future__ import annotations
 
 import dataclasses
-import logging
 from typing import Literal, Sequence
 
-from repro.tuning.autotune import (
-    lookup_blocks,
-    lookup_pallas_capability,
-    lookup_pipeline,
-)
-
-_log = logging.getLogger(__name__)
+from repro.tuning.autotune import lookup_blocks, lookup_pipeline
 
 Backend = Literal["xla", "pallas"]
 ModeHint = Literal["fdsq", "fqsd", "fqsd-streamed"]
@@ -68,16 +61,6 @@ TUNABLE_EXECUTORS = ("fdsq-pallas", "fqsd-int8-pallas")
 PIPELINE_TUNABLE_EXECUTORS = ("fqsd-int8-streamed", "fqsd-int8-mmap-streamed",
                               "fqsd-sharded-int8",
                               "fqsd-sharded-int8-streamed")
-
-#: Fused Pallas executors vetoed on hosts with a persisted interpret-only
-#: capability verdict, and what each falls back to (per logical mode).
-_PALLAS_FALLBACK = {
-    ("fdsq-pallas", "fdsq"): "fdsq-xla",
-    ("fdsq-pallas", "fqsd"): "fqsd-xla",
-    ("fqsd-int8-pallas", "fqsd"): "fqsd-int8",
-}
-
-_capability_warned: set[str] = set()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,6 +181,33 @@ def largest_divisor_at_most(n: int, cap: int) -> int:
                 best = max(best, co)
         d += 1
     return best
+
+
+class PlanRefused(ValueError):
+    """The request names a plan that cannot run as asked (a fused kernel
+    whose tiles do not fit on-chip memory). Raised instead of quietly
+    serving another executor: the caller picks the alternative."""
+
+
+def _check_fused_fits(executor: str, k: int, d: int, blocks, rescore_factor):
+    """Refuse a fused-kernel plan whose tiles cannot fit the kernel's VMEM
+    (estimate and limit: ``repro.kernels.knn.ops.vmem_bytes``). Mosaic
+    would otherwise reject it at the first search, not at planning."""
+    from repro.kernels.knn import ops as knn_ops
+
+    defaults = knn_ops.DEFAULT_BLOCKS
+    bm, bn, bd = (b or dflt for b, dflt in zip(blocks, defaults))
+    need = knn_ops.vmem_bytes(k, d, bm, bn, bd, rescore_factor)
+    if need > knn_ops.VMEM_LIMIT_BYTES:
+        queue = knn_ops.queue_width(k, rescore_factor)
+        raise PlanRefused(
+            f"{executor} refused: k={k}"
+            + ("" if rescore_factor is None
+               else f" with rescore_factor={rescore_factor}")
+            + f" needs a {queue}-wide on-chip queue; its tiles need about "
+            f"{need / 2**20:.1f} MiB of VMEM, over the "
+            f"{knn_ops.VMEM_LIMIT_BYTES / 2**20:.0f} MiB a kernel gets. "
+            "Use backend='xla', a smaller k, or a smaller rescore_factor.")
 
 
 def plan(
@@ -328,28 +338,6 @@ def plan(
         tier = "f32"
         chunk = largest_divisor_at_most(rows, max(1, chunk))
 
-    # capability guard: a persisted interpret-only verdict (see
-    # repro.tuning.probe_pallas_capability) vetoes the fused Pallas
-    # executors — interpret mode is a ~100x slowdown, never worth serving.
-    # No verdict (None) means "never probed": planning stays permissive so
-    # explicit pallas backends keep working on unprobed hosts.
-    if executor in TUNABLE_EXECUTORS and lookup_pallas_capability() is False:
-        fallback = _PALLAS_FALLBACK[(executor, mode)]
-        if executor not in _capability_warned:
-            _capability_warned.add(executor)
-            _log.warning(
-                "planner: %s vetoed (persisted capability verdict says "
-                "Pallas runs in interpret mode on this host); falling "
-                "back to %s", executor, fallback)
-        executor = fallback
-        if executor == "fdsq-xla":
-            n_parts = largest_divisor_at_most(rows, max(1, n_parts))
-        elif executor in ("fqsd-xla", "fqsd-int8"):
-            chunk = largest_divisor_at_most(rows, max(1, chunk))
-        if executor in ("fdsq-xla", "fqsd-xla"):
-            mode_label = mode
-            tier = "f32"
-
     # tuned end-to-end pipeline knobs for the streamed executors (pure
     # cache read, same contract as the block lookup below). The tuned
     # rescore budget applies only when the engine's own budget is not
@@ -383,6 +371,10 @@ def plan(
         )
         if tuned is not None:
             block_m, block_n, block_d = tuned
+        _check_fused_fits(executor, k, int(dataset_meta.padded_dim),
+                          (block_m, block_n, block_d),
+                          int(cfg.rescore_factor)
+                          if executor == "fqsd-int8-pallas" else None)
 
     return ExecutionPlan(
         mode=mode_label,

@@ -33,6 +33,9 @@ via the cancellation form qn - 2<q,x_hat> + ||x_hat||^2, so the certified
 claim (like every exactness claim in this repo, including the oracle) is
 modulo f32 rounding of order ||q||^2 * 2^-24 — the same precision class
 as the f32 scans it certifies against, not a structural bound violation.
+That holds only because every contraction pins ``EXACT_PRECISION``
+(HIGHEST): a TPU's default precision rounds the f32 query through bf16,
+an error of order 2^-8 that the bound does not cover.
 """
 from __future__ import annotations
 
@@ -41,7 +44,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.core.distance import EXACT_PRECISION
 from repro.core.topk import TopK, sort_pairs, topk_smallest
 
 
@@ -103,6 +108,7 @@ def int8_lower_bounds(queries, codes, scales, err, qnorm, base):
         q32, codes.astype(jnp.bfloat16),
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=EXACT_PRECISION,
     ) * scales[None, :]
     d_hat = jnp.maximum(qn - 2.0 * cross + qnorm[None, :], 0.0)
     valid = jnp.isfinite(qnorm)
@@ -178,6 +184,23 @@ def quantize_dataset(x: jax.Array) -> QuantizedDataset:
     return QuantizedDataset(q, scales, err, norms, quantized_norm_sq(q, scales))
 
 
+_quantize_jit = jax.jit(quantize_dataset)
+
+
+def quantize_rows(x, block_rows: int = 65536) -> QuantizedDataset:
+    """:func:`quantize_dataset` of a host (N, d) array, returned as host
+    (numpy) arrays and computed `block_rows` rows at a time.
+
+    Quantization is row-independent, so blocking changes no value; it
+    bounds the device memory one call takes to a block (a whole corpus
+    shard, e.g. 4 GB of GIST or more, would not fit beside its resident
+    f32 view on a 16 GB chip)."""
+    x = np.asarray(x, dtype=np.float32)
+    parts = [jax.device_get(_quantize_jit(x[i: i + block_rows]))
+             for i in range(0, max(1, x.shape[0]), block_rows)]
+    return QuantizedDataset(*(np.concatenate(col) for col in zip(*parts)))
+
+
 def _approx_l2(qv: jax.Array, ds: QuantizedDataset) -> jax.Array:
     """d_hat = ||q - x_hat||^2 using the int8 dataset (f32 queries).
 
@@ -195,6 +218,7 @@ def _approx_l2(qv: jax.Array, ds: QuantizedDataset) -> jax.Array:
         q32, ds.q.astype(jnp.bfloat16),
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=EXACT_PRECISION,
     )
     cross = cross * ds.scales[None, :]
     return jnp.maximum(qn - 2.0 * cross + ds.qnorm_sq[None, :], 0.0)

@@ -37,7 +37,6 @@ from repro.core.distance import Metric, validate_metric
 from repro.core.fqsd import chunk_step
 from repro.core.quantized import int8_lower_bounds
 from repro.core.topk import TopK, empty_topk, sort_pairs, topk_smallest, tree_merge_sorted
-from repro import compat
 
 
 def _local_scan(queries, vectors, norms, k, metric, base, chunk_rows=None):
@@ -99,7 +98,7 @@ def fdsq_sharded(
             state = _gather_merge(state, ax)
         return state
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(axes), P(axes)),
@@ -128,7 +127,7 @@ def fqsd_sharded(
         state = _local_scan(queries, vectors, norms, k, metric, base, chunk_rows)
         return _gather_merge(state, dataset_axis)
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(query_axis), P(dataset_axis), P(dataset_axis)),
@@ -190,7 +189,7 @@ def fqsd_ring(
         )
         return _gather_merge(state, model_axis)
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(query_axis), P((query_axis, model_axis)), P((query_axis, model_axis))),
@@ -204,7 +203,7 @@ def fqsd_ring_queries(
     k: int,
     metric: Metric = "l2",
     query_axis: str = "data",
-    model_axis: str = "model",
+    model_axis: str | None = "model",
 ):
     """Query-direction ring (beyond-paper optimization of `fqsd_ring`).
 
@@ -216,14 +215,23 @@ def fqsd_ring_queries(
     collective-traffic reduction at identical exact results. After D steps
     every block has visited every data row of its model column and is back
     home; one merge over `model` finishes. See EXPERIMENTS.md section Perf.
+
+    Device memory is the stationary shard plus O(m (d + k)), where the
+    dataset ring holds up to three more shards in flight (a 1M x 896 f32
+    shard per v5e device compiles to 10.8 GB of temporaries there), so
+    this is the ring for a corpus larger than one chip.
+    ``model_axis=None`` runs it over a 1-D mesh (the Router's
+    ``("data",)`` device group): the dataset rows shard over `query_axis`
+    alone and the ring itself completes every query block.
     """
     validate_metric(metric)
+    row_axes = (query_axis,) if model_axis is None else (query_axis, model_axis)
 
     def local(queries, vectors, norms):
         d_sz = mesh.shape[query_axis]  # static size, version-safe
-        t_sz = mesh.shape[model_axis]
+        t_sz = 1 if model_axis is None else mesh.shape[model_axis]
         my_d = lax.axis_index(query_axis)
-        my_t = lax.axis_index(model_axis)
+        my_t = 0 if model_axis is None else lax.axis_index(model_axis)
         rows = vectors.shape[0]
         base = (my_d * t_sz + my_t) * rows  # stationary local shard
         perm = [(i, (i + 1) % d_sz) for i in range(d_sz)]
@@ -244,12 +252,14 @@ def fqsd_ring_queries(
         (state, _), _ = lax.scan(
             body, (init, queries), None, length=d_sz, unroll=True)
         # after d_sz rotations the state is back at its owner row
+        if model_axis is None:
+            return state
         return _gather_merge(state, model_axis)
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(P(query_axis), P((query_axis, model_axis)), P((query_axis, model_axis))),
+        in_specs=(P(query_axis), P(row_axes), P(row_axes)),
         out_specs=TopK(P(query_axis), P(query_axis)),
         check_vma=False,
     ))
@@ -294,7 +304,7 @@ def fdsq_sharded_int8(
             state = _gather_merge(state, ax)
         return state
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(axes), P(axes), P(axes), P(axes)),

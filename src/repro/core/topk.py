@@ -16,6 +16,12 @@ On TPU the element-serial queue becomes data-parallel selection:
 
 All selections are exact; ties broken by smaller index (matching a stable
 drain of the paper's queue where earlier-seen elements win ties).
+
+"Exact" is f32 order with subnormals flushed to zero: XLA compares f32
+with flush-to-zero on both the CPU and the TPU, so a subnormal score
+(|s| < 2^-126) orders as a zero of its sign and ties with 0.0 — it is
+returned unchanged, but ranked as zero. Distances that small are below
+the rounding of any real distance computation.
 """
 from __future__ import annotations
 

@@ -45,13 +45,14 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.core.distance import EXACT_PRECISION
 
 from repro.kernels.bitonic import bitonic_sort, tile_prunable, topk_update
 
 
 def _knn_kernel(
     q_ref, x_ref, qn_ref, xn_ref, ov_ref, oi_ref, sk_ref, acc, buf_v, buf_i,
+    n_skip,
     *, k_eff: int, n_steps: int, d_steps: int, bn: int, metric: str,
     prune: bool,
 ):
@@ -62,7 +63,7 @@ def _knn_kernel(
     def _init_queue():
         buf_v[...] = jnp.full_like(buf_v, jnp.inf)
         buf_i[...] = jnp.full_like(buf_i, -1)
-        sk_ref[0, 0] = 0
+        n_skip[0] = 0
 
     @pl.when(kd == 0)
     def _init_acc():
@@ -71,7 +72,7 @@ def _knn_kernel(
     # partial-distance / vector-adder: MXU cross-product accumulation
     acc[...] += lax.dot_general(
         q_ref[...], x_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=EXACT_PRECISION,
     )
 
     @pl.when(kd == d_steps - 1)
@@ -101,7 +102,7 @@ def _knn_kernel(
 
             @pl.when(skip)
             def _count_skip():
-                sk_ref[0, 0] += 1
+                n_skip[0] += 1
         else:
             _merge()
 
@@ -109,6 +110,9 @@ def _knn_kernel(
     def _flush():
         ov_ref[...] = buf_v[...]
         oi_ref[...] = buf_i[...]
+        # the SMEM counter leaves as one lane-aligned (8, 128) block per
+        # m-tile (a (1, 1) block breaks the TPU tiling rule)
+        sk_ref[...] = jnp.full(sk_ref.shape, n_skip[0], jnp.int32)
 
 
 @functools.partial(
@@ -150,7 +154,7 @@ def knn_pallas(
         _knn_kernel, k_eff=k_eff, n_steps=n_steps, d_steps=d_steps, bn=bn,
         metric=metric, prune=prune,
     )
-    return pl.pallas_call(
+    v, i, sk = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -162,20 +166,21 @@ def knn_pallas(
         out_specs=[
             pl.BlockSpec((bm, k_eff), lambda i, j, kd: (i, 0)),
             pl.BlockSpec((bm, k_eff), lambda i, j, kd: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, kd: (i, 0)),
+            pl.BlockSpec((8, 128), lambda i, j, kd: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, k_eff), jnp.float32),
             jax.ShapeDtypeStruct((m, k_eff), jnp.int32),
-            jax.ShapeDtypeStruct((m // bm, 1), jnp.int32),
+            jax.ShapeDtypeStruct((m // bm * 8, 128), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.float32),  # cross-product accumulator
             pltpu.VMEM((bm, k_eff), jnp.float32),  # queue values
             pltpu.VMEM((bm, k_eff), jnp.int32),  # queue indices
+            pltpu.SMEM((1,), jnp.int32),  # skipped merges of this m-tile
         ],
-        compiler_params=compat.tpu_compiler_params(
-            ('parallel', 'arbitrary', 'arbitrary')
-        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary', 'arbitrary')),
         interpret=interpret,
     )(q, x, qn, xn)
+    return v, i, sk[::8, :1]

@@ -37,14 +37,14 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.core.distance import EXACT_PRECISION
 
 from repro.kernels.bitonic import bitonic_sort, tile_prunable, topk_update
 
 
 def _knn_int8_kernel(
     q_ref, x_ref, qn_ref, sc_ref, er_ref, hn_ref, ov_ref, oi_ref, sk_ref,
-    acc, buf_v, buf_i,
+    acc, buf_v, buf_i, n_skip,
     *, q_len: int, n_steps: int, d_steps: int, bn: int, prune: bool,
 ):
     j = pl.program_id(1)
@@ -54,7 +54,7 @@ def _knn_int8_kernel(
     def _init_queue():
         buf_v[...] = jnp.full_like(buf_v, jnp.inf)
         buf_i[...] = jnp.full_like(buf_i, -1)
-        sk_ref[0, 0] = 0
+        n_skip[0] = 0
 
     @pl.when(kd == 0)
     def _init_acc():
@@ -63,7 +63,7 @@ def _knn_int8_kernel(
     # int8 codes widen in VMEM; HBM traffic for the dataset stays 1 B/elem
     acc[...] += lax.dot_general(
         q_ref[...], x_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=EXACT_PRECISION,
     )
 
     @pl.when(kd == d_steps - 1)
@@ -97,7 +97,7 @@ def _knn_int8_kernel(
 
             @pl.when(skip)
             def _count_skip():
-                sk_ref[0, 0] += 1
+                n_skip[0] += 1
         else:
             _merge()
 
@@ -105,6 +105,9 @@ def _knn_int8_kernel(
     def _flush():
         ov_ref[...] = buf_v[...]
         oi_ref[...] = buf_i[...]
+        # the SMEM counter leaves as one lane-aligned (8, 128) block per
+        # m-tile (a (1, 1) block breaks the TPU tiling rule)
+        sk_ref[...] = jnp.full(sk_ref.shape, n_skip[0], jnp.int32)
 
 
 @functools.partial(
@@ -151,7 +154,7 @@ def knn_pallas_int8(
         _knn_int8_kernel, q_len=q_len, n_steps=n_steps, d_steps=d_steps,
         bn=bn, prune=prune,
     )
-    return pl.pallas_call(
+    v, i, sk = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -165,20 +168,21 @@ def knn_pallas_int8(
         out_specs=[
             pl.BlockSpec((bm, q_len), lambda i, j, kd: (i, 0)),
             pl.BlockSpec((bm, q_len), lambda i, j, kd: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, kd: (i, 0)),
+            pl.BlockSpec((8, 128), lambda i, j, kd: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, q_len), jnp.float32),
             jax.ShapeDtypeStruct((m, q_len), jnp.int32),
-            jax.ShapeDtypeStruct((m // bm, 1), jnp.int32),
+            jax.ShapeDtypeStruct((m // bm * 8, 128), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.float32),  # int32->f32 cross accumulator
             pltpu.VMEM((bm, q_len), jnp.float32),  # candidate lower bounds
             pltpu.VMEM((bm, q_len), jnp.int32),  # candidate indices
+            pltpu.SMEM((1,), jnp.int32),  # skipped merges of this m-tile
         ],
-        compiler_params=compat.tpu_compiler_params(
-            ('parallel', 'arbitrary', 'arbitrary')
-        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary', 'arbitrary')),
         interpret=interpret,
     )(q, x8, qn, scales, err, hn)
+    return v, i, sk[::8, :1]

@@ -35,6 +35,16 @@ from repro.kernels.knn.kernel_int8 import knn_pallas_int8
 #: Hand-tuned fallback (bm, bn, bd) used when the autotune cache is cold.
 DEFAULT_BLOCKS = (128, 512, 512)
 
+#: Scoped VMEM Mosaic lets one kernel use on a TPU v5e core when the
+#: kernel sets no ``vmem_limit_bytes`` (the kernels here set none). This is
+#: the compiler's default scope, not the core's physical VMEM (128 MiB on
+#: v5e), and it holds for v5e only: other devices need their own figure.
+VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+
+#: Live (value, index) copies of the (bm, bn) tile while it is sorted: the
+#: tile, both rotations of each array and the selected result.
+SORT_LIVE_PAIRS = 6
+
 
 def _round_up(v: int, m: int) -> int:
     return ((v + m - 1) // m) * m
@@ -56,13 +66,43 @@ def resolved_blocks(
     shapes in kernel_stats. ``rescore_factor=None`` means the f32 kernel
     (queue width k_eff); an int means the int8 kernel (queue width
     2 x next_pow2(rescore_factor * k_eff))."""
-    k_eff = next_pow2(k)
-    if rescore_factor is None:
-        queue = k_eff
-    else:
-        queue = 2 * next_pow2(max(1, rescore_factor) * k_eff)
+    queue = queue_width(k, rescore_factor)
     return (block_m, max(block_n, queue),
             min(block_d, _round_up(max(d, 1), 128)))
+
+
+def queue_width(k: int, rescore_factor: int | None = None) -> int:
+    """Per-query on-chip queue width: k_eff for the f32 kernel, 2r with
+    r = next_pow2(rescore_factor * k_eff) for the int8 kernel."""
+    k_eff = next_pow2(k)
+    if rescore_factor is None:
+        return k_eff
+    return 2 * next_pow2(max(1, rescore_factor) * k_eff)
+
+
+def vmem_bytes(
+    k: int,
+    d: int,
+    block_m: int = DEFAULT_BLOCKS[0],
+    block_n: int = DEFAULT_BLOCKS[1],
+    block_d: int = DEFAULT_BLOCKS[2],
+    rescore_factor: int | None = None,
+) -> int:
+    """Estimated VMEM one fused-kernel instance needs at its resolved tiles.
+
+    Double-buffered query and dataset tiles, the int8 tile's f32 widening,
+    the cross-product accumulator, the queue scratch, and the bitonic
+    sort's working set, counted as SORT_LIVE_PAIRS live (value, index)
+    copies of the (bm, bn) tile. Calibrated against Mosaic's compile for a
+    TPU v5e at the default blocks: the int8 kernel with a 1024-wide queue
+    fits VMEM_LIMIT_BYTES, with a 2048-wide one it runs out of VMEM."""
+    bm, bn, bd = resolved_blocks(k, d, block_m, block_n, block_d,
+                                 rescore_factor)
+    queue = queue_width(k, rescore_factor)
+    x_bytes = 4 if rescore_factor is None else 1
+    widened = 0 if rescore_factor is None else bn * bd * 4
+    return (2 * (bm * bd * 4 + bn * bd * x_bytes) + widened
+            + bm * bn * 4 + bm * queue * 8 + SORT_LIVE_PAIRS * bm * bn * 8)
 
 
 @functools.partial(

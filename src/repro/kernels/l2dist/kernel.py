@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
+from repro.core.distance import EXACT_PRECISION
 
 
 def _l2dist_kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, *, n_d_steps: int):
@@ -40,7 +40,8 @@ def _l2dist_kernel(q_ref, x_ref, qn_ref, xn_ref, o_ref, *, n_d_steps: int):
     q = q_ref[...]
     x = x_ref[...]
     part = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=EXACT_PRECISION,
     )
     o_ref[...] += -2.0 * part
 
@@ -88,8 +89,7 @@ def l2dist_pallas(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kd: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
-            ('parallel', 'parallel', 'arbitrary')
-        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
         interpret=interpret,
     )(q, x, qn, xn)

@@ -24,8 +24,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 from repro.kernels.bitonic import bitonic_sort, topk_update
 
 
@@ -89,8 +87,7 @@ def topk_pallas(
             pltpu.VMEM((bm, k_eff), jnp.float32),
             pltpu.VMEM((bm, k_eff), jnp.int32),
         ],
-        compiler_params=compat.tpu_compiler_params(
-            ('parallel', 'arbitrary')
-        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary')),
         interpret=interpret,
     )(scores)

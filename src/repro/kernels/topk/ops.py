@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.partition import next_pow2
 from repro.kernels.topk.kernel import topk_pallas
 
 

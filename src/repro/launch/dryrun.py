@@ -1,11 +1,18 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x shape) cell on
 the production meshes and record memory/cost/collective artifacts.
 
-THE two lines above must execute before any other import (jax locks the
-device count at first backend init), hence the unusual module layout.
+CPU-only tool: the lines above give JAX 512 fake host-platform devices and
+keep it off any accelerator, in this process and in the worker processes
+``--jobs`` starts, so it never holds a chip. They must execute before any
+other import (jax locks the device count at first backend init), hence the
+unusual module layout. Its roofline uses the v5e peaks it targets
+(``repro.roofline.hw.TPU_V5E``); its numbers are compile-time estimates,
+never measurements. To compile a kernel for a real v5e without the chip,
+describe the topology instead (see tests/test_tpu_compile.py).
 
 Methodology notes (see EXPERIMENTS.md section Dry-run):
 * memory_analysis comes from the full-depth scan-over-layers compile — the
@@ -35,9 +42,21 @@ import sys  # noqa: E402
 import time  # noqa: E402
 import traceback  # noqa: E402
 
-from repro import compat
-
 ARTIFACTS = pathlib.Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
+
+
+def _named(mesh, specs):
+    """PartitionSpecs (None = replicated) as NamedShardings on `mesh`."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    import jax
+
+    def wrap(s):
+        return NamedSharding(mesh, PartitionSpec() if s is None else s)
+
+    return jax.tree.map(
+        wrap, specs,
+        is_leaf=lambda s: s is None or isinstance(s, PartitionSpec))
 
 
 def _compile_cell(arch, shape, mesh):
@@ -45,7 +64,7 @@ def _compile_cell(arch, shape, mesh):
 
     from repro.launch.steps import build_cell
 
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         cell = build_cell(arch, shape, smoke=False)
         donate = ()
         if shape.kind in ("train", "train_sampled", "train_batched"):
@@ -53,8 +72,8 @@ def _compile_cell(arch, shape, mesh):
         elif shape.kind == "decode":
             donate = (1,)
         jf = jax.jit(cell.fn,
-                     in_shardings=compat.jit_shardings(mesh, cell.in_specs),
-                     out_shardings=compat.jit_shardings(mesh, cell.out_specs),
+                     in_shardings=_named(mesh, cell.in_specs),
+                     out_shardings=_named(mesh, cell.out_specs),
                      donate_argnums=donate)
         lowered = jf.lower(*cell.inputs)
         compiled = lowered.compile()
@@ -64,7 +83,7 @@ def _compile_cell(arch, shape, mesh):
 def _cost_triple(compiled, chips):
     from repro.roofline.analysis import collective_bytes_from_hlo
 
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes_from_hlo(compiled.as_text(), default_group=chips)
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)),
@@ -123,7 +142,7 @@ def run_cell(arch_id: str, shape_name: str, mesh_kind: str) -> dict:
 
     roof = roofline_terms(
         f_pd * chips, b_pd * chips, w_pd, chips,
-        model_flops=cell.meta.get("model_flops", 0))
+        model_flops=cell.meta.get("model_flops", 0), hw=TPU_V5E)
     roof.collective_ops = {k: {"count": coll.op_counts[k],
                                "bytes": coll.op_bytes[k]} for k in coll.op_counts}
 

@@ -13,8 +13,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -28,7 +26,9 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             f"production mesh needs {n} devices, found {len(devices)} — "
             "run under launch/dryrun.py (sets xla_force_host_platform_device_count)"
         )
-    return compat.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices[:n])
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
@@ -36,4 +36,5 @@ def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     n = len(jax.devices())
     if data * model > n:
         raise ValueError(f"mesh {data}x{model} needs {data*model} devices, have {n}")
-    return compat.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

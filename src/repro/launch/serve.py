@@ -97,25 +97,29 @@ def _shard_fraction(text: str) -> float:
     return v
 
 
-def _build_router(args):
+def _build_router(args, x=None, store=None, **engine_overrides):
     """Build the Router + collection both the replay and the HTTP front
     end serve (single construction path: --verify-on-open, int8, and every
-    engine knob behave identically in both modes)."""
+    engine knob behave identically in both modes).
+
+    The corpus is ``vector_dataset(--n, --d, seed=--seed)`` unless the
+    caller passes its rows (`x`) or a prebuilt, possibly out-of-core
+    DatasetStore (`store`); `engine_overrides` are further ExactKNN
+    arguments (e.g. ``device_budget_bytes``)."""
     from repro.api import Router
     from repro.data import vector_dataset
-    from repro.tuning import probe_pallas_capability
 
-    # probe-once capability verdict: persisted in the per-device autotune
-    # cache so every later plan() on this host refuses interpret-mode
-    # Pallas executors (a ~100x slowdown) with a logged reason
-    probe_pallas_capability()
-    x = vector_dataset(args.n, args.d, seed=0)
+    if x is None and store is None:
+        x = vector_dataset(args.n, args.d, seed=args.seed)
     router = Router()
     engine_kw = dict(k=args.k, n_partitions=args.partitions,
+                     backend=args.backend,
                      prefetch_depth=args.prefetch_depth,
                      spec_trigger=args.spec_trigger,
-                     max_retries=args.max_retries)
-    if args.verify_on_open or args.compact_min_pending is not None:
+                     max_retries=args.max_retries, **engine_overrides)
+    if store is not None:
+        router.create(args.collection, store=store, **engine_kw)
+    elif args.verify_on_open or args.compact_min_pending is not None:
         # write the corpus through the disk store and reopen it verified:
         # full CRC audit at open, plus CRC-on-read armed on every streamed
         # shard for the life of the server. A disk-backed store is also
@@ -199,7 +203,7 @@ def serve_knn(args):
         return
     policy = "throughput" if args.fqsd else args.policy
     router, x = _build_router(args)
-    q = query_stream(x, args.queries, seed=1)
+    q = query_stream(x, args.queries, seed=args.seed + 1)
     sched = AdaptiveScheduler(
         policy=policy,
         fdsq_max_batch=args.fdsq_max_batch, fqsd_min_depth=args.fqsd_min_depth,
@@ -277,13 +281,19 @@ def serve_lm(args):
           f"({tok/dt:.1f} tok/s, continuous batching over 4 slots)")
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI (also the one way chip_smoke.py configures it)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["knn", "lm"], default="knn")
     ap.add_argument("--n", type=int, default=20000)
     ap.add_argument("--d", type=int, default=128)
     ap.add_argument("--k", type=int, default=10)
     ap.add_argument("--queries", type=int, default=100)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the generated corpus (queries use seed+1)")
+    ap.add_argument("--backend", choices=["xla", "pallas"], default="xla",
+                    help="scan backend: XLA executors, or the fused Pallas "
+                         "kernels (fdsq-pallas / fqsd-int8-pallas)")
     ap.add_argument("--partitions", type=int, default=8)
     ap.add_argument("--policy", choices=["latency", "throughput", "adaptive"],
                     default="latency")
@@ -355,7 +365,14 @@ def main(argv=None):
                          "queue before the server answers 503; default: "
                          "wait for dispatch (--listen only)")
     ap.add_argument("--arch", default="minicpm-2b")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.mode == "knn":
         serve_knn(args)
     else:

@@ -31,7 +31,6 @@ from repro.models.sampler import edge_budget
 from repro.optim import adamw_init, adamw_update, apply_updates
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.sharding import resolve, sanitize_tree
-from repro import compat
 
 
 @dataclasses.dataclass
@@ -239,7 +238,7 @@ def _lm_meta(cfg: T.LMConfig, batch, seq, train: bool, decode: bool = False):
 
 
 def _mesh():
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     return None if (m is None or m.empty) else m
 
 

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.runtime.sharding import shard
-from repro import compat
 
 
 def _dense_init(key, shape, dtype, scale=None):
@@ -223,7 +222,7 @@ def decode_attention(params, x, cache_k, cache_v, cache_len, *,
     slot = (jnp.arange(s_max, dtype=jnp.int32) == cache_len)[None, :, None, None]
     new_k = jnp.where(slot, k.astype(cache_k.dtype), cache_k)
     new_v = jnp.where(slot, v.astype(cache_v.dtype), cache_v)
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     s_max = cache_k.shape[1]
     tp = mesh.axis_sizes[mesh.axis_names.index("model")] if (
         mesh is not None and not mesh.empty and "model" in mesh.axis_names) else 1
@@ -289,7 +288,7 @@ def _flash_decode_sharded(q, k, v, cache_len, *, mesh, n_heads, n_kv_heads, d_he
         denom = jnp.maximum(denom, 1e-30)
         return (o / denom.transpose(0, 2, 1)[..., None]).astype(q_loc.dtype)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(b_entry, None, None, None),
@@ -344,7 +343,7 @@ def moe(params, x, *, n_experts: int, top_k: int, capacity_factor: float = 1.25)
 
     Returns (y, aux) with aux = load-balance loss (Switch-style).
     """
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is not None and not mesh.empty and "model" in mesh.axis_names:
         return _moe_manual(params, x, n_experts=n_experts, top_k=top_k,
                            capacity_factor=capacity_factor, mesh=mesh)
@@ -473,7 +472,7 @@ def _moe_manual(params, x, *, n_experts: int, top_k: int,
         aux = lax.pmean(aux, ("model",) + tuple(data_axes))
         return y.reshape(bl, s, d).astype(x.dtype), aux
 
-    return compat.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(b_entry, None, None), P(), P("model"), P("model"), P("model")),

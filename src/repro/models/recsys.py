@@ -24,7 +24,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.runtime.sharding import resolve, shard
-from repro import compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,7 +90,7 @@ class RecsysConfig:
 def embedding_lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
     """ids (...,) -> (..., dim). Row-sharded tables resolve via shard-local
     masked take + psum when a `model` mesh axis is active."""
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or "model" not in mesh.axis_names:
         return jnp.take(table, ids, axis=0)
 
@@ -113,7 +112,7 @@ def embedding_lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
         vals = jnp.where(ok[..., None], vals, 0)
         return lax.psum(vals, "model")
 
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("model"), batch_spec), out_specs=out_spec,
         check_vma=False,

@@ -15,7 +15,7 @@ import dataclasses
 import re
 from typing import Mapping
 
-from repro.roofline.hw import TPU_V5E, HwSpec
+from repro.roofline.hw import HwSpec
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -129,7 +129,8 @@ def roofline_terms(
     wire_per_device: float,
     chips: int,
     model_flops: float = 0.0,
-    hw: HwSpec = TPU_V5E,
+    *,
+    hw: HwSpec,
 ) -> Roofline:
     compute = hlo_flops / (chips * hw.peak_flops_bf16)
     memory = hlo_bytes / (chips * hw.hbm_bw)
@@ -152,7 +153,8 @@ def roofline_terms(
     )
 
 
-def analyze_compiled(compiled, chips: int, model_flops: float, hw: HwSpec = TPU_V5E) -> Roofline:
+def analyze_compiled(compiled, chips: int, model_flops: float, *,
+                     hw: HwSpec) -> Roofline:
     """Full analysis of a jax compiled executable.
 
     cost_analysis() on the SPMD-partitioned module reports PER-DEVICE
@@ -160,9 +162,7 @@ def analyze_compiled(compiled, chips: int, model_flops: float, hw: HwSpec = TPU_
     4 ways reports 2mnk/4 flops). Global = per-device x chips, matching the
     brief's `HLO_FLOPs / (chips * peak)` convention.
     """
-    from repro import compat
-
-    cost: Mapping = compat.cost_analysis(compiled)
+    cost: Mapping = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0)) * chips
     byts = float(cost.get("bytes accessed", 0.0)) * chips
     hlo = compiled.as_text()

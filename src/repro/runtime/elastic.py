@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -34,8 +33,10 @@ def best_mesh_shape(n_devices: int, prefer_model: int = 16) -> tuple[int, int]:
 def remesh(devices=None, prefer_model: int = 16) -> jax.sharding.Mesh:
     devices = list(devices if devices is not None else jax.devices())
     data, model = best_mesh_shape(len(devices), prefer_model)
-    return compat.make_mesh(
-        (data, model), ("data", "model"), devices=devices[: data * model])
+    return jax.make_mesh(
+        (data, model), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        devices=devices[: data * model])
 
 
 def reshard_tree(tree, specs, mesh: jax.sharding.Mesh):

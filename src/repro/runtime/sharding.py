@@ -20,7 +20,6 @@ from typing import Mapping, Sequence
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 # logical axis -> mesh axis (or tuple of mesh axes)
 DEFAULT_RULES: dict[str, object] = {
@@ -57,7 +56,7 @@ def axis_rules(rules: Mapping[str, object]):
 
 
 def _mesh_axis_names() -> tuple[str, ...]:
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return ()
     return tuple(mesh.axis_names)
@@ -85,7 +84,7 @@ def resolve(logical: Sequence[str | None]) -> P:
 
 
 def _mesh_axis_sizes() -> Mapping[str, int]:
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return {}
     return dict(zip(mesh.axis_names, mesh.axis_sizes))

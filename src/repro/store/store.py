@@ -987,12 +987,12 @@ class DatasetStore:
     def _quantize_generation(self, g: _Generation) -> None:
         """Build (and for directory-backed generations, persist) the int8
         tier of every shard in `g`, updating its manifest in place."""
-        from repro.core.quantized import quantize_dataset
+        from repro.core.quantized import quantize_rows
 
         shards: list[Int8Shard] = []
         metas: list[ShardMeta] = []
         for s in g.shards:
-            qd = quantize_dataset(np.asarray(s.vectors))
+            qd = quantize_rows(s.vectors)
             norms = np.asarray(qd.norms_sq).copy()
             norms[s.meta.n_valid:] = np.inf
             i8 = Int8Shard(np.asarray(qd.q), np.asarray(qd.scales),

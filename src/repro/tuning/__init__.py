@@ -1,16 +1,14 @@
-"""repro.tuning — per-device autotuning: kernel block shapes, end-to-end
-streamed-pipeline knobs, and host capability verdicts.
+"""repro.tuning — per-device autotuning: kernel block shapes and end-to-end
+streamed-pipeline knobs.
 
     AutotuneCache       JSON-persisted per-device cache (artifacts/autotune/)
-                        holding three entry kinds keyed by prefix:
-                        block shapes, "pipe|" pipeline knobs, "capability|"
+                        holding two entry kinds keyed by prefix:
+                        block shapes and "pipe|" pipeline knobs
     autotune_knn        sweep legal (bm, bn, bd) on the live device
     autotune_pipeline   sweep (prefetch_depth, spec_trigger, rescore_factor,
                         rows_per_shard) with whole timed searches
     lookup_blocks       pure read the planner uses to fill plan blocks
     lookup_pipeline     pure read the planner uses for streamed plans
-    lookup_pallas_capability / probe_pallas_capability
-                        interpret-mode guard: probe once, plan() reads
     candidate_blocks    the legality-filtered sweep space for one key
 """
 from repro.tuning.autotune import (
@@ -23,10 +21,8 @@ from repro.tuning.autotune import (
     default_cache,
     device_kind,
     lookup_blocks,
-    lookup_pallas_capability,
     lookup_pipeline,
     pipeline_key,
-    probe_pallas_capability,
     set_default_cache,
     tuning_key,
 )
@@ -34,7 +30,6 @@ from repro.tuning.autotune import (
 __all__ = [
     "AutotuneCache", "BlockShapes", "PipelineKnobs", "autotune_knn",
     "autotune_pipeline", "candidate_blocks", "default_cache", "device_kind",
-    "lookup_blocks", "lookup_pallas_capability", "lookup_pipeline",
-    "pipeline_key", "probe_pallas_capability", "set_default_cache",
+    "lookup_blocks", "lookup_pipeline", "pipeline_key", "set_default_cache",
     "tuning_key",
 ]

@@ -32,21 +32,14 @@ factor set the on-chip queue width, so each gets its own tuning entry).
 M is bucketed to the next power of two — the serving layer already pads
 batches that way, so tuning inherits the same O(log max_batch) key space.
 
-Beyond per-kernel block shapes, the same cache persists two more entry
-kinds (distinguished by key prefix, validated per-kind on load):
+Beyond per-kernel block shapes, the same cache persists one more entry
+kind (distinguished by key prefix, validated per-kind on load):
 
     pipe|<executor>|m<pow2>|n<rows>|d<dim>|<dtype>|<metric>|k<k>
         -> PipelineKnobs(prefetch_depth, spec_trigger, rescore_factor,
            rows_per_shard) — the end-to-end winner of
         :func:`autotune_pipeline` for one streamed-int8 problem; consumed
         by ``planner.plan()`` so streamed plans carry tuned pipeline knobs.
-
-    capability|pallas
-        -> {"compiled": bool} — whether the fused Pallas kernels compile
-        natively on this host (vs. interpret mode, a ~100x slowdown).
-        Written once by :func:`probe_pallas_capability`; ``planner.plan()``
-        refuses to emit a fused executor when a persisted verdict says
-        interpret-only and falls back to the XLA scan with a logged reason.
 
 See ``src/repro/tuning/README.md`` for the sweep spaces and how to
 pre-seed caches for CI.
@@ -65,10 +58,6 @@ DEFAULT_CACHE_DIR = os.path.join("artifacts", "autotune")
 BM_CANDIDATES = (8, 32, 128, 256)
 BN_CANDIDATES = (256, 512, 1024, 2048)
 BD_CANDIDATES = (128, 256, 512)
-
-#: VMEM budget for (q tile + x tile + accumulator + queues); real cores
-#: have ~16 MB, keep headroom for double buffering.
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
 class BlockShapes(NamedTuple):
@@ -95,13 +84,6 @@ class PipelineKnobs(NamedTuple):
     rows_per_shard: int
 
 
-def _next_pow2(v: int) -> int:
-    p = 1
-    while p < v:
-        p <<= 1
-    return p
-
-
 def _round_up(v: int, m: int) -> int:
     return ((v + m - 1) // m) * m
 
@@ -119,7 +101,9 @@ def tuning_key(kernel: str, m: int, n: int, d: int, dtype: str,
     would otherwise be re-clamped past the vetted VMEM legality under
     another.
     """
-    key = (f"{kernel}|m{_next_pow2(max(1, int(m)))}|n{int(n)}|d{int(d)}"
+    from repro.core.partition import next_pow2
+
+    key = (f"{kernel}|m{next_pow2(max(1, int(m)))}|n{int(n)}|d{int(d)}"
            f"|{dtype}|{metric}|k{int(k)}")
     if rescore_factor is not None:
         key += f"|r{int(rescore_factor)}"
@@ -135,11 +119,10 @@ def pipeline_key(executor: str, m: int, n: int, d: int, dtype: str,
     on the same planner-visible geometry. rescore_factor is NOT part of
     the key — it is one of the swept knobs, stored in the entry value.
     """
-    return (f"pipe|{executor}|m{_next_pow2(max(1, int(m)))}|n{int(n)}"
+    from repro.core.partition import next_pow2
+
+    return (f"pipe|{executor}|m{next_pow2(max(1, int(m)))}|n{int(n)}"
             f"|d{int(d)}|{dtype}|{metric}|k{int(k)}")
-
-
-CAPABILITY_KEY = "capability|pallas"
 
 
 def _validate_entry(key: str, e: dict) -> None:
@@ -147,8 +130,6 @@ def _validate_entry(key: str, e: dict) -> None:
     if key.startswith("pipe|"):
         PipelineKnobs(int(e["prefetch_depth"]), float(e["spec_trigger"]),
                       int(e["rescore_factor"]), int(e["rows_per_shard"]))
-    elif key.startswith("capability|"):
-        bool(e["compiled"])
     else:
         BlockShapes(int(e["block_m"]), int(e["block_n"]), int(e["block_d"]))
 
@@ -263,35 +244,6 @@ class AutotuneCache:
         }
         self.save()
 
-    def get_capability(self, name: str = "pallas") -> bool | None:
-        """Persisted capability verdict, or None if never probed."""
-        self._ensure()
-        e = self._entries.get(f"capability|{name}")
-        if e is None or "compiled" not in e:
-            return None
-        return bool(e["compiled"])
-
-    def put_capability(self, compiled: bool, name: str = "pallas",
-                       **meta) -> None:
-        self._ensure()
-        self._entries[f"capability|{name}"] = {"compiled": bool(compiled),
-                                               **meta}
-        self.save()
-
-    def without_capability(self) -> "AutotuneCache":
-        """In-memory view of this cache minus capability verdicts.
-
-        For benchmarks that measure the fused Pallas path *explicitly*
-        (e.g. kernels_bench on a CPU host): tuned block/pipeline entries
-        stay visible to the planner, but a persisted interpret-only
-        verdict no longer vetoes the executor under measurement.
-        """
-        self._ensure()
-        view = AutotuneCache(path=None)
-        view._entries = {k: dict(v) for k, v in self._entries.items()
-                         if not k.startswith("capability|")}
-        return view
-
     def __len__(self) -> int:
         self._ensure()
         return len(self._entries)
@@ -349,55 +301,28 @@ def lookup_pipeline(executor: str, m: int, n: int, d: int, dtype: str,
         return None
 
 
-def lookup_pallas_capability() -> bool | None:
-    """Pure read: persisted Pallas verdict for this device, else None.
-
-    None means "never probed" — the planner treats that as capable, so
-    plain planning stays probe-free; only an explicitly persisted
-    interpret-only verdict (see :func:`probe_pallas_capability`) vetoes
-    the fused executors.
-    """
-    try:
-        return default_cache().get_capability("pallas")
-    except Exception:
-        return None
-
-
-def probe_pallas_capability(cache: AutotuneCache | None = None) -> bool:
-    """Probe whether the fused Pallas kernels compile natively here and
-    persist the verdict under ``capability|pallas``.
-
-    The fused kernels themselves decide interpret mode by backend
-    (``ops.knn``: interpret unless the default backend is TPU), so the
-    probe mirrors that decision instead of timing a canary — one static
-    check, persisted once, consulted by every subsequent ``plan()``.
-    Called explicitly at serving/bench startup, never implicitly from
-    planning (planning must stay pure and device-free).
-    """
-    import jax
-
-    if cache is None:
-        cache = default_cache()
-    compiled = jax.default_backend() == "tpu"
-    cache.put_capability(compiled, backend=jax.default_backend())
-    return compiled
-
-
 # --------------------------------------------------------------- sweeping
 def candidate_blocks(
     m: int,
     n: int,
     d: int,
-    queue_len: int,
-    dtype_bytes: int = 4,
-    vmem_budget_bytes: int = VMEM_BUDGET_BYTES,
+    k: int,
+    rescore_factor: int | None = None,
 ) -> list[BlockShapes]:
     """Legal (bm, bn, bd) sweep for one problem (ops.py pads to any of
-    these, so legality = queue fits the tile + VMEM budget holds).
+    these, so legality = the tile holds the on-chip queue, bd stays within
+    the padded dim, and the kernel's VMEM estimate fits).
 
-    queue_len is the per-query on-chip queue width (k_eff for the f32
-    kernel, 2 * rescore budget for int8); bn must be able to hold it.
+    ``rescore_factor=None`` sweeps the f32 kernel (queue width k_eff); an
+    int sweeps the int8 kernel (queue width 2 x its rescore budget). VMEM
+    is judged by the same estimate and limit the planner refuses plans
+    with (``kernels.knn.ops.vmem_bytes`` / ``VMEM_LIMIT_BYTES``), so every
+    candidate the sweep can persist is one the planner will serve. Empty
+    when no tile fits.
     """
+    from repro.kernels.knn import ops as knn_ops
+
+    queue_len = knn_ops.queue_width(k, rescore_factor)
     d_pad = _round_up(max(1, d), 128)
     out: list[BlockShapes] = []
     for bm in BM_CANDIDATES:
@@ -409,25 +334,9 @@ def candidate_blocks(
             for bd in BD_CANDIDATES:
                 if bd > d_pad:
                     continue
-                # sub-f32 dataset tiles are widened to f32 in VMEM before
-                # the MXU dot (x_ref[...].astype(f32)), so both the raw
-                # tile and its widened copy count against the budget
-                x_tile = bn * bd * dtype_bytes
-                if dtype_bytes < 4:
-                    x_tile += bn * bd * 4
-                vmem = (
-                    bm * bd * 4            # query tile (f32)
-                    + x_tile               # dataset tile (+ f32 widening)
-                    + bm * bn * 4          # accumulator
-                    + bm * queue_len * 8   # queue values + indices
-                    + bm * 8               # epilogue rows
-                )
-                if vmem <= vmem_budget_bytes:
+                if (knn_ops.vmem_bytes(k, d, bm, bn, bd, rescore_factor)
+                        <= knn_ops.VMEM_LIMIT_BYTES):
                     out.append(BlockShapes(bm, bn, bd))
-    if not out:  # degenerate budget: at least offer the smallest legal tile
-        out.append(BlockShapes(BM_CANDIDATES[0],
-                               max(BN_CANDIDATES[0], queue_len),
-                               min(BD_CANDIDATES[0], d_pad)))
     return out
 
 
@@ -477,7 +386,6 @@ def autotune_knn(
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core.partition import next_pow2
     from repro.kernels.knn import ops as knn_ops
 
     if cache is None:
@@ -486,14 +394,11 @@ def autotune_knn(
     q = jnp.asarray(rng.standard_normal((m, d)), dtype=dtype)
     x = jnp.asarray(rng.standard_normal((n, d)), dtype=dtype)
 
-    k_eff = next_pow2(k)
     if tier == "int8":
         from repro.core.quantized import quantize_dataset
 
         kernel = "fqsd-int8-pallas"
         ds = quantize_dataset(x)
-        queue_len = 2 * next_pow2(max(1, rescore_factor) * k_eff)
-        dtype_bytes = 1
 
         def run(blocks: BlockShapes):
             fn = functools.partial(
@@ -505,8 +410,6 @@ def autotune_knn(
                               repeats=repeats)
     elif tier == "f32":
         kernel = "fdsq-pallas"
-        queue_len = k_eff
-        dtype_bytes = jnp.dtype(dtype).itemsize
 
         def run(blocks: BlockShapes):
             fn = functools.partial(
@@ -518,7 +421,13 @@ def autotune_knn(
     else:
         raise ValueError(f"unknown tier {tier!r}; known: f32, int8")
 
-    cands = candidate_blocks(m, n, d, queue_len, dtype_bytes=dtype_bytes)
+    key_factor = rescore_factor if tier == "int8" else None
+    cands = candidate_blocks(m, n, d, k, key_factor)
+    if not cands:
+        raise ValueError(
+            f"no ({kernel}) tile holds the {knn_ops.queue_width(k, key_factor)}"
+            f"-wide on-chip queue within "
+            f"{knn_ops.VMEM_LIMIT_BYTES / 2**20:.0f} MiB of VMEM")
     if max_candidates is not None:
         cands = cands[:max_candidates]
     timings: dict[str, float] = {}
@@ -529,8 +438,7 @@ def autotune_knn(
         timings[f"{blocks.block_m}x{blocks.block_n}x{blocks.block_d}"] = t
         if t < best_t:
             best, best_t = blocks, t
-    assert best is not None  # candidate_blocks never returns empty
-    key_factor = rescore_factor if tier == "int8" else None
+    assert best is not None  # cands is not empty
     cache.put(
         tuning_key(kernel, m, n, d, dtype, metric, k, key_factor), best,
         us_per_call=best_t * 1e6, n_candidates=len(cands),

@@ -1,10 +1,10 @@
 """Shared fixtures: isolate every test from the per-device autotune cache.
 
 The planner consults the process-wide ``repro.tuning.default_cache()`` for
-tuned block shapes, pipeline knobs, and the persisted Pallas capability
-verdict. Tests must neither read a developer machine's warm cache (which
-would silently change planned executors/blocks) nor write to it (a probe
-inside one test would veto Pallas for every later planner test). Each test
+tuned block shapes and pipeline knobs. Tests must neither read a
+developer machine's warm cache (which would silently change planned
+blocks or knobs) nor write to it (a sweep inside one test would retune
+every later planner test). Each test
 therefore runs against a fresh in-memory cache; tests that exercise
 persistence pass their own ``path=`` explicitly.
 """

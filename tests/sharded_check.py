@@ -12,7 +12,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import (  # noqa: E402
     ExactKNN,
     fdsq_sharded,
@@ -42,7 +41,7 @@ def main():
     ds = make_padded(x, row_mult=1024)  # divisible by 8 shards
     qp = jnp.pad(jnp.asarray(q), ((0, 0), (0, ds.vectors.shape[1] - d)))
 
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         # FD-SQ over the whole mesh
         f = fdsq_sharded(mesh, k)
         v, nn = shard_dataset(mesh, ds.vectors, ds.norms, ("data", "model"))

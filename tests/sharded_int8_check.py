@@ -21,7 +21,6 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from adversarial_cases import QUANT_CASES  # noqa: E402
-from repro import compat  # noqa: E402
 from repro.api import Router, SearchRequest  # noqa: E402
 from repro.core import ExactKNN, cache_info, clear_executable_cache  # noqa: E402
 from repro.core.fqsd import streamed_direct_scan  # noqa: E402
@@ -241,9 +240,10 @@ def check_router_placement():
 
 def main():
     assert len(jax.devices()) == N_DEV, jax.devices()
-    mesh = compat.make_mesh((N_DEV,), ("data",))
+    mesh = jax.make_mesh((N_DEV,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     clear_executable_cache()
-    with compat.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         check_resident_quant_cases(mesh)
         check_ring_quant_cases(mesh)
         with tempfile.TemporaryDirectory() as tmpdir:

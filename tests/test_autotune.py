@@ -8,6 +8,8 @@ import numpy as np
 import pytest
 
 from repro.core import ExactKNN, cache_info, clear_executable_cache
+from repro.core.planner import DatasetStoreMeta, EngineConfig, plan
+from repro.kernels.knn import ops as knn_ops
 from repro.tuning import (
     AutotuneCache,
     BlockShapes,
@@ -16,10 +18,8 @@ from repro.tuning import (
     autotune_pipeline,
     candidate_blocks,
     lookup_blocks,
-    lookup_pallas_capability,
     lookup_pipeline,
     pipeline_key,
-    probe_pallas_capability,
     set_default_cache,
     tuning_key,
 )
@@ -89,37 +89,70 @@ class TestCacheRoundTrip:
 
 class TestCandidateLegality:
     def test_candidates_respect_queue_and_dim(self):
-        cands = candidate_blocks(m=16, n=4096, d=100, queue_len=1024)
+        cands = candidate_blocks(m=16, n=4096, d=100, k=1024)
         assert cands
         for c in cands:
             assert c.block_n >= 1024  # queue must fit the tile sort
             assert c.block_d <= 128  # d=100 pads to 128, never beyond
 
     def test_vmem_budget_filters(self):
-        small = candidate_blocks(m=256, n=1 << 20, d=1024, queue_len=128,
-                                 vmem_budget_bytes=1 << 20)
-        for c in small:
-            vmem = (c.block_m * c.block_d * 4 + c.block_n * c.block_d * 4
-                    + c.block_m * c.block_n * 4)
-            assert vmem <= (1 << 20) or (c,) == tuple(small)  # fallback only
+        """The sweep keeps exactly the tiles whose kernel VMEM estimate
+        fits the limit: at k=100, d=1024 the widest sort tiles do not."""
+        cands = candidate_blocks(m=256, n=1 << 20, d=1024, k=100)
+        assert cands
+        for c in cands:
+            assert knn_ops.vmem_bytes(100, 1024, *c) \
+                <= knn_ops.VMEM_LIMIT_BYTES
+        assert BlockShapes(256, 2048, 512) not in cands
+        assert knn_ops.vmem_bytes(100, 1024, 256, 2048, 512) \
+            > knn_ops.VMEM_LIMIT_BYTES
 
     def test_int8_widening_counted_against_vmem(self):
         """The kernel widens the int8 dataset tile to f32 in VMEM before
-        the MXU dot, so int8 legality must charge 1+4 B/elem for it — a
-        1 B/elem model would admit tiles ~3 MB past the budget."""
-        budget = 2 << 20
-        cands = candidate_blocks(m=128, n=1 << 20, d=2048, queue_len=64,
-                                 dtype_bytes=1, vmem_budget_bytes=budget)
+        the MXU dot, so int8 legality must charge 1+4 B/elem for it: this
+        tile fits at 1 B/elem but not with its widened copy."""
+        k, d, rf = 8, 2048, 4
+        cands = candidate_blocks(m=128, n=1 << 20, d=d, k=k,
+                                 rescore_factor=rf)
+        bm, bn, bd = 128, 2048, 512
+        need = knn_ops.vmem_bytes(k, d, bm, bn, bd, rf)
+        assert need - bn * bd * 4 <= knn_ops.VMEM_LIMIT_BYTES < need
+        assert BlockShapes(bm, bn, bd) not in cands
+        assert cands
+
+    @pytest.mark.parametrize("executor,k,d,rf", [
+        ("fdsq-pallas", 10, 1024, None),
+        ("fdsq-pallas", 100, 960, None),
+        ("fdsq-pallas", 1024, 4096, None),
+        ("fqsd-int8-pallas", 100, 896, 4),
+    ])
+    def test_every_candidate_passes_the_planner(self, executor, k, d, rf):
+        """A persisted sweep winner is served, never refused: the planner
+        plans every candidate the sweep offers with those very blocks."""
+        m, n = 128, 1 << 20
+        tier = "f32" if rf is None else "int8"
+        meta = DatasetStoreMeta(padded_rows=n, padded_dim=d, n_valid=n,
+                                tier=tier)
+        cfg = EngineConfig(k=k, backend="pallas", rescore_factor=rf or 4)
+        cands = candidate_blocks(m, n, d, k, rf)
+        assert cands
         for c in cands:
-            widened = (c.block_m * c.block_d * 4
-                       + c.block_n * c.block_d * (1 + 4)
-                       + c.block_m * c.block_n * 4)
-            assert widened <= budget or (c,) == tuple(cands)  # fallback only
+            cache = AutotuneCache(path=None)
+            cache.put(tuning_key(executor, m, n, d, "float32", "l2", k, rf),
+                      c)
+            set_default_cache(cache)
+            p = plan((m, d), meta, cfg, "fqsd")
+            assert p.executor == executor
+            assert (p.block_m, p.block_n, p.block_d) == tuple(c)
 
     def test_degenerate_budget_still_returns_one(self):
-        cands = candidate_blocks(m=1, n=128, d=8, queue_len=512,
-                                 vmem_budget_bytes=1)
-        assert len(cands) == 1 and cands[0].block_n >= 512
+        """No tile holds an int8 queue 2 x 16384 wide within VMEM: the
+        sweep offers none and the tuner refuses instead of persisting a
+        tile the planner would refuse."""
+        assert candidate_blocks(m=1, n=1 << 16, d=128, k=4096,
+                                rescore_factor=4) == []
+        with pytest.raises(ValueError, match="32768-wide on-chip queue"):
+            autotune_knn(8, 256, 128, k=4096, tier="int8", repeats=1)
 
 
 class TestSweepAndPlanner:
@@ -264,9 +297,10 @@ class TestPipelineEntries:
         assert cache.get_pipeline(KEY) is None
 
     def test_load_drops_only_bad_entries(self, tmp_path):
-        """Mixed-kind cache with one malformed pipe entry: the bad entry
-        is dropped on load, the good block and capability entries survive
-        (pre-ISSUE-6 loading nuked the whole cache)."""
+        """Mixed-kind cache with one malformed pipe entry and one entry of
+        a kind this version no longer reads: both are dropped on load, the
+        good block entry survives (an older loader dropped the whole
+        cache)."""
         path = str(tmp_path / "cpu.json")
         payload = {
             "schema_version": 1,
@@ -281,7 +315,7 @@ class TestPipelineEntries:
         cache = AutotuneCache(path)
         assert cache.get(KEY) == BlockShapes(32, 512, 128)
         assert cache.get_pipeline(PIPE_KEY) is None
-        assert cache.get_capability("pallas") is False
+        assert set(cache.keys()) == {KEY}
 
     def test_lookup_pipeline_consults_default_cache(self):
         assert lookup_pipeline("fqsd-int8-streamed", 8, 1024, 128,
@@ -291,34 +325,6 @@ class TestPipelineEntries:
         set_default_cache(cache)
         assert lookup_pipeline("fqsd-int8-streamed", 8, 1024, 128,
                                "float32", "l2", 10) == KNOBS
-
-
-class TestCapability:
-    def test_unprobed_is_none(self, tmp_path):
-        assert AutotuneCache(str(tmp_path / "c.json")).get_capability() is None
-        assert lookup_pallas_capability() is None
-
-    def test_probe_persists_verdict(self, tmp_path):
-        cache = AutotuneCache(str(tmp_path / "c.json"))
-        verdict = probe_pallas_capability(cache=cache)
-        # off-TPU hosts run the fused kernels in interpret mode
-        import jax
-        assert verdict == (jax.default_backend() == "tpu")
-        assert AutotuneCache(str(tmp_path / "c.json")).get_capability() \
-            == verdict
-
-    def test_without_capability_view(self, tmp_path):
-        cache = AutotuneCache(str(tmp_path / "c.json"))
-        cache.put(KEY, BlockShapes(32, 512, 128))
-        cache.put_pipeline(PIPE_KEY, KNOBS)
-        cache.put_capability(False)
-        view = cache.without_capability()
-        assert view.get_capability() is None
-        assert view.get(KEY) == BlockShapes(32, 512, 128)
-        assert view.get_pipeline(PIPE_KEY) == KNOBS
-        # the view is detached: mutating it never touches the file
-        view.put_capability(True)
-        assert AutotuneCache(str(tmp_path / "c.json")).get_capability() is False
 
 
 class TestPipelineSweep:

@@ -141,53 +141,6 @@ class TestPerRequestPlanOverrides:
             plan((8, 128), META, CFG, "fqsd", k=0)
 
 
-class TestCapabilityGuard:
-    """ISSUE 6 satellite: a persisted interpret-only verdict must veto the
-    fused Pallas executors at plan time (probing is explicit and happens
-    elsewhere — planning itself stays pure cache reads)."""
-
-    INT8_META = DatasetStoreMeta(padded_rows=2048, padded_dim=128,
-                                 n_valid=2000, tier="int8")
-
-    def _verdict(self, compiled):
-        from repro.tuning import AutotuneCache, set_default_cache
-
-        cache = AutotuneCache(path=None)
-        cache.put_capability(compiled)
-        set_default_cache(cache)
-
-    def test_interpret_only_verdict_falls_back_to_xla(self):
-        self._verdict(False)
-        cfg = dataclasses.replace(CFG, backend="pallas")
-        lat = plan((1, 128), META, cfg, "fdsq")
-        assert lat.executor == "fdsq-xla" and lat.mode == "fdsq"
-        assert META.padded_rows % lat.n_partitions == 0
-        thr = plan((64, 128), META, cfg, "fqsd")
-        assert thr.executor == "fqsd-xla" and thr.mode == "fqsd"
-        assert META.padded_rows % thr.chunk_rows == 0
-        i8 = plan((8, 128), self.INT8_META, cfg, "fqsd")
-        assert i8.executor == "fqsd-int8" and i8.tier == "int8"
-
-    def test_compiled_verdict_keeps_pallas(self):
-        self._verdict(True)
-        cfg = dataclasses.replace(CFG, backend="pallas")
-        assert plan((1, 128), META, cfg, "fdsq").executor == "fdsq-pallas"
-        assert plan((8, 128), self.INT8_META, cfg, "fqsd").executor \
-            == "fqsd-int8-pallas"
-
-    def test_unprobed_host_stays_permissive(self):
-        # conftest installs an empty cache == never probed: explicit pallas
-        # backends must keep planning the fused executor (covers every
-        # pre-existing CPU pallas test and bench)
-        cfg = dataclasses.replace(CFG, backend="pallas")
-        assert plan((1, 128), META, cfg, "fdsq").executor == "fdsq-pallas"
-
-    def test_guard_never_touches_xla_plans(self):
-        self._verdict(False)
-        assert plan((4, 128), META, CFG, "fdsq").executor == "fdsq-xla"
-        assert plan((64, 128), META, CFG, "fqsd").executor == "fqsd-xla"
-
-
 class TestPipelineKnobsOnPlan:
     """ISSUE 6 tentpole: tuned pipeline knobs land on streamed-int8 plans
     and ride the plan cache key (tuned vs untuned plans must never collide
