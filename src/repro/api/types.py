@@ -149,8 +149,8 @@ class SearchResult:
                   rows were recomputed in f32 by the executor.
     kernel_stats  fused-kernel observability (pruning skip rate, resolved
                   tile shapes); None for non-Pallas executors.
-    stats         per-request accounting: bytes_scanned, dispatch_ms,
-                  batched, deadline_ms/latency_ms (serving), k, metric;
+    stats         per-request accounting: bytes_scanned, batched,
+                  deadline_ms/latency_ms (serving), k, metric;
                   streamed int8 adds the wall-time split (scan_ms /
                   gather_ms / rescore_ms) and a "speculation" block
                   (trigger, rows_speculated, rows_topped_up, rows_wasted —

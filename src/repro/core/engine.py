@@ -50,7 +50,6 @@ no ``if mesh`` / ``if backend`` dispatch of its own.
 """
 from __future__ import annotations
 
-import time
 import warnings
 from typing import Iterable, Sequence
 
@@ -770,7 +769,6 @@ class ExactKNN:
         max_retries = (self.max_retries if request.max_retries is None
                        else int(request.max_retries))
         allow_partial = bool(request.allow_partial)
-        t0 = time.perf_counter()
         # every read below goes through the pinned view when one exists, so
         # a mid-search generation swap cannot mix shards of two epochs
         src_store = view if view is not None else self._store
@@ -831,7 +829,6 @@ class ExactKNN:
             # the indices channel; scores and ordering are untouched)
             idx = np.asarray(jax.device_get(out.indices))
             out = TopK(out.scores, jnp.asarray(view.external_ids(idx)))
-        dispatch_ms = (time.perf_counter() - t0) * 1e3
         ctx = self._last_ctx
         cert = ctx.certificate if (ctx is not None and p.tier == "int8") else None
         stats = {
@@ -845,7 +842,6 @@ class ExactKNN:
                 else p.padded_rows * p.padded_dim
                 * (1 if p.tier == "int8" else 4)
             ),
-            "dispatch_ms": dispatch_ms,
         }
         if ctx is not None and ctx.stream_stats is not None:
             stats["transfers"] = ctx.stream_stats.get("transfers", 0)

@@ -61,11 +61,17 @@ def chunk_step(
     n_valid: jax.Array | int,
     metric: Metric = "l2",
 ) -> TopK:
-    """Insert one dataset partition into the running queues (exact)."""
-    s = _masked_scores(queries, chunk, chunk_norms, n_valid, metric)
-    idx = base_index + jnp.arange(chunk.shape[0], dtype=jnp.int32)
-    idx = jnp.broadcast_to(idx[None, :], s.shape)
-    return merge_topk(state, s, idx)
+    """Insert one dataset partition into the running queues (exact).
+
+    The two stages carry stable scope names in the compiled program's op
+    metadata: ``knn.distance_tile`` (the masked (M, chunk) score tile) and
+    ``knn.chunk_merge`` (its merge into the k running queues)."""
+    with jax.named_scope("knn.distance_tile"):
+        s = _masked_scores(queries, chunk, chunk_norms, n_valid, metric)
+    with jax.named_scope("knn.chunk_merge"):
+        idx = base_index + jnp.arange(chunk.shape[0], dtype=jnp.int32)
+        idx = jnp.broadcast_to(idx[None, :], s.shape)
+        return merge_topk(state, s, idx)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "chunk_rows"))

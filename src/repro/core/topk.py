@@ -123,22 +123,25 @@ def tree_merge_sorted(parts_scores: jax.Array, parts_indices: jax.Array) -> TopK
     """Merge P per-partition results, (P, ..., k) -> (..., k), via a binary tree.
 
     O(log P) merge stages instead of a serial O(P) chain — the multi-chip
-    generalization of the paper's single shared FD-SQ queue.
+    generalization of the paper's single shared FD-SQ queue. Its ops carry
+    the scope name ``knn.tree_merge`` in the compiled program's metadata.
     """
-    s = parts_scores.astype(jnp.float32)
-    i = parts_indices.astype(jnp.int32)
-    k = s.shape[-1]
-    while s.shape[0] > 1:
-        p = s.shape[0]
-        if p % 2:  # pad with one empty (drained) queue
-            s = jnp.concatenate([s, jnp.full_like(s[:1], jnp.inf)], axis=0)
-            i = jnp.concatenate([i, jnp.full_like(i[:1], INVALID_INDEX)], axis=0)
-            p += 1
-        half = p // 2
-        cat_s = jnp.concatenate([s[:half], s[half:]], axis=-1)  # (half, ..., 2k)
-        cat_i = jnp.concatenate([i[:half], i[half:]], axis=-1)
-        s, i = topk_smallest(cat_s, cat_i, k)
-    return TopK(s[0], i[0])
+    with jax.named_scope("knn.tree_merge"):
+        s = parts_scores.astype(jnp.float32)
+        i = parts_indices.astype(jnp.int32)
+        k = s.shape[-1]
+        while s.shape[0] > 1:
+            p = s.shape[0]
+            if p % 2:  # pad with one empty (drained) queue
+                s = jnp.concatenate([s, jnp.full_like(s[:1], jnp.inf)], axis=0)
+                i = jnp.concatenate([i, jnp.full_like(i[:1], INVALID_INDEX)],
+                                    axis=0)
+                p += 1
+            half = p // 2
+            cat_s = jnp.concatenate([s[:half], s[half:]], axis=-1)  # (half, ..., 2k)
+            cat_i = jnp.concatenate([i[:half], i[half:]], axis=-1)
+            s, i = topk_smallest(cat_s, cat_i, k)
+        return TopK(s[0], i[0])
 
 
 def knn_oracle(

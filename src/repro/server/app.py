@@ -26,6 +26,12 @@ Engine dispatch runs on ONE shared worker thread (the compiled-executable
 cache and the engines are single-threaded by design); the event loop
 itself only parses, admits, and streams, so thousands of connections ride
 one accelerator dispatch stream.
+
+Every request records its spans (``repro.tracing``) under one request id:
+``request`` from the request read to the response drained, and within a
+search ``parse``, ``admit``, ``queue`` (closed by the batch that takes it),
+``encode`` and ``write``; every other reply records its ``write``. The
+server's lifetime is the ``serve`` span.
 """
 from __future__ import annotations
 
@@ -42,6 +48,7 @@ from repro.serving.retrieval import AdaptiveScheduler
 from repro.server import protocol
 from repro.server.admission import AdmissionController
 from repro.server.batching import ContinuousBatcher, ServerClosed
+from repro.tracing import TRACER
 
 __all__ = ["KnnServer"]
 
@@ -116,6 +123,7 @@ class KnnServer:
                 int8_min_depth=int8_min_depth,
             )
         self._server: asyncio.base_events.Server | None = None
+        self._serving = None  # the open ``serve`` span
         self._ws_streams = 0
         self.connections = 0
 
@@ -128,6 +136,7 @@ class KnnServer:
         self._server = await asyncio.start_server(
             self._handle, self.host, self.port)
         self.port = self._server.sockets[0].getsockname()[1]
+        self._serving = TRACER.span("serve", TRACER.new_id())
 
     @property
     def address(self) -> tuple[str, int]:
@@ -145,6 +154,9 @@ class KnnServer:
         for batcher in self.batchers.values():
             await batcher.stop()
         self._executor.shutdown(wait=True)
+        if self._serving is not None:
+            self._serving.close()
+            self._serving = None
 
     async def __aenter__(self) -> "KnnServer":
         await self.start()
@@ -173,8 +185,9 @@ class KnnServer:
                     if e.close:
                         return
                     continue
+                span = TRACER.span("request", TRACER.new_id())
                 try:
-                    done = await self._route(req, reader, writer)
+                    done = await self._route(req, reader, writer, span.id)
                 except (ConnectionResetError, BrokenPipeError):
                     return  # peer vanished mid-response
                 except protocol.ProtocolError as e:
@@ -190,6 +203,8 @@ class KnnServer:
                         500, {"error": "internal server error"}, close=True))
                     await writer.drain()
                     done = True
+                finally:
+                    span.close()
                 if done or not req.keep_alive:
                     return
         except (ConnectionResetError, BrokenPipeError, asyncio.CancelledError):
@@ -202,15 +217,16 @@ class KnnServer:
 
     async def _route(self, req: protocol.HttpRequest,
                      reader: asyncio.StreamReader,
-                     writer: asyncio.StreamWriter) -> bool:
-        """Dispatch one request; returns True when the connection is done
-        (WebSocket sessions own the connection until close)."""
+                     writer: asyncio.StreamWriter, rid: int) -> bool:
+        """Dispatch one request (`rid`: its id in the spans); returns True
+        when the connection is done (WebSocket sessions own the connection
+        until close)."""
         path = req.path
         if path == "/healthz":
-            await self._respond(writer, 200, self._healthz())
+            await self._respond(writer, rid, 200, self._healthz())
             return False
         if path == "/stats":
-            await self._respond(writer, 200, self._stats())
+            await self._respond(writer, rid, 200, self._stats())
             return False
         if path == "/v1/stats/stream":
             await self._stats_stream(req, reader, writer)
@@ -229,56 +245,66 @@ class KnnServer:
                 return False
             if action == "search":
                 _require_post(req)
-                await self._search(name, req, writer)
+                await self._search(name, req, writer, rid)
                 return False
             if action == "upsert":
                 _require_post(req)
-                await self._upsert(name, req, writer)
+                await self._upsert(name, req, writer, rid)
                 return False
             if action == "delete":
                 _require_post(req)
-                await self._delete(name, req, writer)
+                await self._delete(name, req, writer, rid)
                 return False
             if action == "compact":
-                await self._compact(name, req, writer)
+                await self._compact(name, req, writer, rid)
                 return False
         raise _not_found(path)
 
-    async def _respond(self, writer, status, payload, headers=None) -> None:
-        writer.write(protocol.http_response(status, payload, headers=headers))
-        await writer.drain()
+    async def _respond(self, writer, rid: int, status, payload,
+                       headers=None) -> None:
+        await self._send(
+            writer, protocol.http_response(status, payload, headers=headers),
+            rid)
+
+    @staticmethod
+    async def _send(writer, data: bytes, rid: int) -> None:
+        with TRACER.span("write", rid):
+            writer.write(data)
+            await writer.drain()
 
     # ----------------------------------------------------------------- search
     async def _search(self, name: str, req: protocol.HttpRequest,
-                      writer: asyncio.StreamWriter) -> None:
+                      writer: asyncio.StreamWriter, rid: int) -> None:
         loop = asyncio.get_running_loop()
         engine = self.router.engine(name)
-        request, tenant = protocol.parse_search_request(
-            req.json(), arrival_s=loop.time(), n_ids=engine.n_ids)
-        if request.n_queries() > 1:
-            # the continuous batcher IS the batching layer (same contract
-            # as AdaptiveScheduler): one query per request, the server
-            # amortizes the scan across tenants
-            raise protocol.BadRequest(
-                "send one query per request (the server batches for you); "
-                f"got {request.n_queries()} rows"
-            )
-        tenant = req.headers.get("x-tenant", tenant)
+        with TRACER.span("parse", rid):
+            request, tenant = protocol.parse_search_request(
+                req.json(), arrival_s=loop.time(), n_ids=engine.n_ids)
+            if request.n_queries() > 1:
+                # the continuous batcher IS the batching layer (same
+                # contract as AdaptiveScheduler): one query per request,
+                # the server amortizes the scan across tenants
+                raise protocol.BadRequest(
+                    "send one query per request (the server batches for "
+                    f"you); got {request.n_queries()} rows"
+                )
+            tenant = req.headers.get("x-tenant", tenant)
         batcher = self.batchers[name]
-        verdict = self.admission.try_admit(
-            tenant, deadline_ms=request.deadline_ms,
-            predicted_wait_s=batcher.predicted_wait_s())
+        with TRACER.span("admit", rid):
+            verdict = self.admission.try_admit(
+                tenant, deadline_ms=request.deadline_ms,
+                predicted_wait_s=batcher.predicted_wait_s())
         if not verdict.admitted:
             retry_after = max(verdict.retry_after_s, 1e-3)
             await self._respond(
-                writer, verdict.status,
+                writer, rid, verdict.status,
                 {"error": f"admission rejected: {verdict.reason}",
                  "reason": verdict.reason,
                  "retry_after_ms": retry_after * 1e3},
                 headers={"Retry-After": f"{retry_after:.3f}"})
             return
         try:
-            fut = batcher.submit(request)
+            fut = batcher.submit(request, rid)
             timeout = (None if self.queue_timeout_ms is None
                        else self.queue_timeout_ms / 1e3)
             try:
@@ -287,7 +313,7 @@ class KnnServer:
                 # wait_for cancelled the future: the batcher drops it at
                 # batch-formation time, so the dispatch slot is never spent
                 await self._respond(
-                    writer, 503,
+                    writer, rid, 503,
                     {"error": "request timed out in the serving queue",
                      "reason": "queue_timeout",
                      "retry_after_ms": self.queue_timeout_ms},
@@ -295,27 +321,30 @@ class KnnServer:
                              f"{self.queue_timeout_ms / 1e3:.3f}"})
                 return
             except ServerClosed as e:
-                await self._respond(writer, 503, {"error": str(e)})
+                await self._respond(writer, rid, 503, {"error": str(e)})
                 return
             except FaultError as e:
                 # unrecoverable storage fault under strict semantics (the
                 # breaker below threshold stays loud by contract)
-                await self._respond(writer, 503, {
+                await self._respond(writer, rid, 503, {
                     "error": str(e), "reason": "storage_fault",
                     "shard": getattr(e, "shard_id", -1)})
                 return
             except (ValueError, TypeError) as e:
                 # engine-level validation the boundary could not see
                 # (e.g. int8 tier never enabled on this collection)
-                await self._respond(writer, 400, {"error": str(e)})
+                await self._respond(writer, rid, 400, {"error": str(e)})
                 return
-            await self._respond(writer, 200, protocol.encode_result(result))
+            with TRACER.span("encode", rid):
+                data = protocol.http_response(
+                    200, protocol.encode_result(result))
+            await self._send(writer, data, rid)
         finally:
             self.admission.release(tenant)
 
     # ------------------------------------------------------------- mutations
     async def _upsert(self, name: str, req: protocol.HttpRequest,
-                      writer: asyncio.StreamWriter) -> None:
+                      writer: asyncio.StreamWriter, rid: int) -> None:
         payload = req.json()
         if not isinstance(payload, dict) or "vectors" not in payload:
             raise protocol.BadRequest("upsert body must be "
@@ -339,11 +368,11 @@ class KnnServer:
                 self._executor, self.router.upsert, name, vec)
         except (ValueError, TypeError) as e:
             raise protocol.BadRequest(str(e)) from None
-        await self._respond(writer, 200,
+        await self._respond(writer, rid, 200,
                             {"ids": np.asarray(ids), "count": len(ids)})
 
     async def _delete(self, name: str, req: protocol.HttpRequest,
-                      writer: asyncio.StreamWriter) -> None:
+                      writer: asyncio.StreamWriter, rid: int) -> None:
         payload = req.json()
         if not isinstance(payload, dict) or "ids" not in payload:
             raise protocol.BadRequest('delete body must be {"ids": [...]}')
@@ -361,11 +390,11 @@ class KnnServer:
                 self._executor, self.router.delete, name, ids)
         except (ValueError, TypeError, KeyError, IndexError) as e:
             raise protocol.BadRequest(str(e)) from None
-        await self._respond(writer, 200, {"deleted": int(ids.size)})
+        await self._respond(writer, rid, 200, {"deleted": int(ids.size)})
 
     # ------------------------------------------------------------- compaction
     async def _compact(self, name: str, req: protocol.HttpRequest,
-                       writer: asyncio.StreamWriter) -> None:
+                       writer: asyncio.StreamWriter, rid: int) -> None:
         """POST triggers compaction of the collection's store; GET reads its
         status. The default trigger is asynchronous (the store's own
         background compactor thread — searches keep streaming their pinned
@@ -373,7 +402,7 @@ class KnnServer:
         status); ``{"wait": true}`` runs it to completion on the dispatch
         worker (admin tooling, tests) so the response reflects the swap."""
         if req.method == "GET":
-            await self._respond(writer, 200,
+            await self._respond(writer, rid, 200,
                                 self.router.compaction_status(name))
             return
         _require_post(req)
@@ -394,7 +423,7 @@ class KnnServer:
                 status = self.router.compact(name, False)
         except (ValueError, RuntimeError) as e:
             raise protocol.BadRequest(str(e)) from None
-        await self._respond(writer, 200, status)
+        await self._respond(writer, rid, 200, status)
 
     # ----------------------------------------------------------------- stats
     def _healthz(self) -> dict:

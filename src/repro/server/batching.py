@@ -23,6 +23,12 @@ reject, and stream stats while the accelerator crunches.
 Results resolve per-request futures as their dispatch completes; a future
 whose waiter vanished (client disconnect, queue timeout) is skipped at
 batch-formation time, so dead requests never occupy dispatch slots.
+
+Each dispatch records its spans (``repro.tracing``) under a dispatch id:
+``batch`` from the take to the last future set (value: the requests
+taken), with ``take`` and ``deliver``, the batcher's own work on the event
+loop. A request's ``queue`` span opens at ``submit`` and closes when a
+batch takes it, linked to that dispatch.
 """
 from __future__ import annotations
 
@@ -31,6 +37,7 @@ import functools
 from collections import deque
 
 from repro.api.types import SearchRequest
+from repro.tracing import TRACER, Span
 
 __all__ = ["ContinuousBatcher", "ServerClosed"]
 
@@ -50,7 +57,7 @@ class ContinuousBatcher:
     def __init__(self, scheduler, executor):
         self.scheduler = scheduler
         self._executor = executor
-        self._queue: deque[tuple[SearchRequest, asyncio.Future]] = deque()
+        self._queue: deque[tuple[SearchRequest, asyncio.Future, Span]] = deque()
         self._wakeup = asyncio.Event()
         self._task: asyncio.Task | None = None
         self._closed = False
@@ -61,12 +68,17 @@ class ContinuousBatcher:
         self.dispatched_batches = 0
 
     # ---------------------------------------------------------------- intake
-    def submit(self, request: SearchRequest) -> asyncio.Future:
-        """Enqueue one admitted request; resolves to its SearchResult."""
+    def submit(self, request: SearchRequest,
+               rid: int | None = None) -> asyncio.Future:
+        """Enqueue one admitted request; resolves to its SearchResult.
+
+        `rid` is the request's id in the spans (a new one when not given):
+        its ``queue`` span opens here and closes when a batch takes it."""
         if self._closed:
             raise ServerClosed("server is shutting down")
         fut = asyncio.get_running_loop().create_future()
-        self._queue.append((request, fut))
+        queued = TRACER.span("queue", TRACER.new_id() if rid is None else rid)
+        self._queue.append((request, fut, queued))
         self.scheduler.note_queue_depth(len(self._queue))
         self._wakeup.set()
         return fut
@@ -86,25 +98,38 @@ class ContinuousBatcher:
         return batches_ahead * est
 
     # -------------------------------------------------------------- dispatch
-    def _take_batch(self) -> list[tuple[SearchRequest, asyncio.Future]]:
+    def _take_batch(self, dispatch_id: int
+                    ) -> list[tuple[SearchRequest, asyncio.Future, Span]]:
         """Pop the next option-compatible batch; drop abandoned entries."""
         sig = None
-        batch: list[tuple[SearchRequest, asyncio.Future]] = []
+        batch: list[tuple[SearchRequest, asyncio.Future, Span]] = []
         take = max(1, self.scheduler.max_batch)
         while self._queue and len(batch) < take:
-            req, fut = self._queue[0]
+            req, fut, queued = self._queue[0]
             if fut.done():  # cancelled by timeout/disconnect: skip it
                 self._queue.popleft()
+                queued.close()
                 continue
             key = self.scheduler.batch_signature(req)
             if sig is None:
                 sig = key
             elif key != sig:
                 break  # next compatibility group waits for its own dispatch
-            batch.append((req, fut))
-            self._queue.popleft()
+            batch.append(self._queue.popleft())
+            queued.parent = dispatch_id
+            queued.close()
         self.scheduler.note_queue_depth(len(self._queue))
         return batch
+
+    def _dispatch(self, dispatch_id: int, reqs: list[SearchRequest],
+                  clock: float):
+        """On the worker thread: the scheduler's dispatch, its spans under
+        this batch's dispatch id."""
+        TRACER.set_dispatch(dispatch_id)
+        try:
+            return self.scheduler.dispatch_batch(reqs, clock)
+        finally:
+            TRACER.set_dispatch(0)
 
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
@@ -114,34 +139,42 @@ class ContinuousBatcher:
             if self._closed:
                 break
             while self._queue:
-                batch = self._take_batch()
+                dispatch_id = TRACER.new_id()
+                span = TRACER.span("batch", dispatch_id)
+                with TRACER.span("take", dispatch_id):
+                    batch = self._take_batch(dispatch_id)
                 if not batch:
+                    span.drop()
                     continue
-                reqs = [r for r, _ in batch]
+                span.value = len(batch)
+                reqs = [r for r, _, _ in batch]
                 clock = loop.time()
                 t0 = clock
                 try:
                     results = await loop.run_in_executor(
                         self._executor,
                         functools.partial(
-                            self.scheduler.dispatch_batch, reqs, clock),
+                            self._dispatch, dispatch_id, reqs, clock),
                     )
                 except Exception as e:  # engine/storage error: per-request
-                    for _, fut in batch:
+                    for _, fut, _ in batch:
                         if not fut.done():
                             fut.set_exception(e)
                 else:
-                    dt = loop.time() - t0
-                    ema = self._ewma_dispatch_s
-                    self._ewma_dispatch_s = (
-                        dt if ema is None else 0.7 * ema + 0.3 * dt)
-                    self.dispatched_batches += 1
-                    for (_, fut), res in zip(batch, results):
-                        if not fut.done():
-                            fut.set_result(res)
+                    with TRACER.span("deliver", dispatch_id):
+                        dt = loop.time() - t0
+                        ema = self._ewma_dispatch_s
+                        self._ewma_dispatch_s = (
+                            dt if ema is None else 0.7 * ema + 0.3 * dt)
+                        self.dispatched_batches += 1
+                        for (_, fut, _), res in zip(batch, results):
+                            if not fut.done():
+                                fut.set_result(res)
+                span.close()
         # drain: everything still queued is answered with ServerClosed
         while self._queue:
-            _, fut = self._queue.popleft()
+            _, fut, queued = self._queue.popleft()
+            queued.close()
             if not fut.done():
                 fut.set_exception(ServerClosed("server is shutting down"))
         self.scheduler.note_queue_depth(0)

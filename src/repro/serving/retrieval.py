@@ -64,6 +64,7 @@ from repro.core.engine import ExactKNN
 from repro.core.partition import next_pow2
 from repro.core.topk import TopK
 from repro.faults import FaultError
+from repro.tracing import TRACER
 
 Policy = Literal["latency", "throughput", "adaptive"]
 
@@ -149,6 +150,8 @@ class AdaptiveScheduler:
     #: dispatch labels stats are bucketed by ("fqsd-int8" = the FQ-SD
     #: logical configuration served from the int8 storage tier)
     MODES = ("fdsq", "fqsd", "fqsd-int8")
+    #: latencies per mode that stats()'s percentiles are taken over
+    LATENCY_WINDOW = 65536
 
     def __init__(
         self,
@@ -214,7 +217,10 @@ class AdaptiveScheduler:
         self._breaker_trips = 0
         self._breaker_probes = 0
         self._breaker_shards: set[int] = set()  # shards implicated so far
-        self._lat_ms: dict[str, list[float]] = {m: [] for m in self.MODES}
+        # the last LATENCY_WINDOW latencies of each mode: stats()'s
+        # percentiles, bounded for a long-lived server
+        self._lat_ms: dict[str, deque[float]] = {
+            m: deque(maxlen=self.LATENCY_WINDOW) for m in self.MODES}
         self._svc_s: dict[str, float] = {m: 0.0 for m in self.MODES}
         self._count: dict[str, int] = {m: 0 for m in self.MODES}
         self._ema_s: dict[str, float | None] = {m: None for m in self.MODES}
@@ -378,141 +384,158 @@ class AdaptiveScheduler:
         log2(max_batch) distinct plans — without it every new depth would
         compile a fresh executable in the serving hot path, violating the
         no-reflashing property the scheduler exists to exploit.
+
+        Spans (``repro.tracing``), under the dispatch's id: ``worker`` the
+        whole call (value: rows; label: the dispatch's mode), and within
+        it ``prepare`` (stack and pad; value: padded rows) and ``wait``
+        (the one device sync).
         """
         self.dispatches += 1
-        t0 = time.perf_counter()
-        rows = []
-        for r in reqs:
-            v = np.asarray(r.queries, dtype=np.float32)
-            if v.ndim == 2 and v.shape[0] == 1:
-                v = v[0]
-            if v.ndim != 1:
-                raise ValueError(
-                    "the scheduler serves single-query requests (batching is "
-                    "its job); send one SearchRequest per query, got queries "
-                    f"of shape {v.shape}"
-                )
-            rows.append(v)
-        q = np.stack(rows)
         b = len(reqs)
-        b_pad = next_pow2(b)
-        if b_pad > b:  # zero rows: row-independent scoring, results sliced off
-            q = np.concatenate([q, np.zeros((b_pad - b, q.shape[1]), q.dtype)])
-        head = reqs[0]
         label = "fqsd-int8" if tier == "int8" else mode
-        if self._breaker_open and self._probe_store():
-            # the probe read succeeded: the storage fault cleared — close
-            # the breaker and serve strict again
-            self._breaker_open = False
-            self._breaker_failures = 0
-        allow_partial = head.allow_partial or self._breaker_open
+        with TRACER.span("worker", TRACER.dispatch_id(), value=b,
+                         label=label) as worker:
+            t0 = time.perf_counter()
+            with TRACER.span("prepare", worker.id) as prepare:
+                rows = []
+                for r in reqs:
+                    v = np.asarray(r.queries, dtype=np.float32)
+                    if v.ndim == 2 and v.shape[0] == 1:
+                        v = v[0]
+                    if v.ndim != 1:
+                        raise ValueError(
+                            "the scheduler serves single-query requests "
+                            "(batching is its job); send one SearchRequest "
+                            f"per query, got queries of shape {v.shape}"
+                        )
+                    rows.append(v)
+                q = np.stack(rows)
+                b_pad = next_pow2(b)
+                if b_pad > b:  # zero rows: row-independent scoring,
+                    # results sliced off
+                    q = np.concatenate(
+                        [q, np.zeros((b_pad - b, q.shape[1]), q.dtype)])
+                prepare.value = len(q)
+            head = reqs[0]
+            if self._breaker_open and self._probe_store():
+                # the probe read succeeded: the storage fault cleared — close
+                # the breaker and serve strict again
+                self._breaker_open = False
+                self._breaker_failures = 0
+            allow_partial = head.allow_partial or self._breaker_open
 
-        def dispatch(partial_ok: bool) -> SearchResult:
-            return self._search(SearchRequest(
-                queries=q, k=head.k, metric=head.metric,
-                tier="int8" if tier == "int8" else "f32",
-                mode_hint="fqsd" if tier == "int8" else mode,
-                filter_mask=head.filter_mask,
-                allow_partial=partial_ok, max_retries=head.max_retries,
-            ))
+            def dispatch(partial_ok: bool) -> SearchResult:
+                return self._search(SearchRequest(
+                    queries=q, k=head.k, metric=head.metric,
+                    tier="int8" if tier == "int8" else "f32",
+                    mode_hint="fqsd" if tier == "int8" else mode,
+                    filter_mask=head.filter_mask,
+                    allow_partial=partial_ok, max_retries=head.max_retries,
+                ))
 
-        try:
-            batch = dispatch(allow_partial)
-        except FaultError as e:
-            # unrecoverable storage fault under strict semantics: count it
-            # toward the breaker; once open, retry this dispatch degraded
-            # (partial allowed) instead of failing the serve loop — below
-            # the threshold, stay loud.
-            self._breaker_note(
-                {"failed_shards": [getattr(e, "shard_id", -1)]})
-            if allow_partial or not self._breaker_open:
-                raise
-            batch = dispatch(True)
-        else:
-            self._breaker_note(batch.stats.get("health"))
-        scores = np.asarray(batch.scores)[:b]  # forces execution (device sync)
-        indices = np.asarray(batch.indices)[:b]
-        dt_s = time.perf_counter() - t0
-
-        plan = batch.plan
-        self._executors[label].add(plan.executor)
-        # dataset bytes one scan of this plan moved (the bandwidth account
-        # choose_tier optimizes), reported per tier AND per dispatch label
-        self._bytes_scanned[batch.tier if batch.tier == "int8" else "f32"] += (
-            batch.stats["bytes_scanned"]
-        )
-        self._tiers[label].add(batch.tier)
-        self._mode_bytes[label] += batch.stats["bytes_scanned"]
-        if batch.tier == "int8":
-            cert = np.asarray(batch.certified)[:b]
-            n_true = int(cert.sum())
-        else:
-            cert = None  # exact path: trivially certified
-            n_true = b
-        self._cert[label]["total"] += b
-        self._cert[label]["true"] += n_true
-        ks = batch.kernel_stats
-        if ks is not None and "prune_skip_rate" in ks:
-            # float() is a free sync here: results were materialized above
-            self._skip_rate_sum += float(ks["prune_skip_rate"])
-            self._skip_rate_n += 1
-        self._transfers += int(batch.stats.get("transfers", 0))
-        self._restarts += int(batch.stats.get("restarts", 0))
-        if "scan_ms" in batch.stats:  # streamed AND sharded int8 plans
-            # report the same scan/gather/rescore wall-time split — mesh
-            # dispatches aggregate here exactly like single-device ones
-            self._phase_n += 1
-            for key in self._phase_ms:
-                self._phase_ms[key] += float(batch.stats.get(key, 0.0))
-        per_dev = batch.stats.get("bytes_per_device")
-        if per_dev is not None:  # sharded dispatch: per-device scan bytes
-            if len(per_dev) > len(self._device_bytes):
-                self._device_bytes.extend(
-                    [0] * (len(per_dev) - len(self._device_bytes)))
-            for di, nbytes in enumerate(per_dev):
-                self._device_bytes[di] += int(nbytes)
-        spec = batch.stats.get("speculation")
-        if spec is not None:
-            self._speculation["dispatches"] += 1
-            for key in ("rows_speculated", "rows_topped_up", "rows_wasted"):
-                self._speculation[key] += int(spec.get(key, 0))
-        health = batch.stats.get("health")
-        if health is not None:
-            self._health_agg["retries"] += int(health.get("retries", 0))
-            for key in ("failed_shards", "degraded", "slow_shards"):
-                self._health_agg[key].update(health.get(key, ()))
-        partial = bool(batch.stats.get("partial", False))
-        if self._last_mode is not None and label != self._last_mode:
-            self._switches += 1
-        self._last_mode = label
-        ema = self._ema_s[label]
-        self._ema_s[label] = dt_s if ema is None else 0.7 * ema + 0.3 * dt_s
-        self._svc_s[label] += dt_s
-        self._count[label] += len(reqs)
-
-        results = []
-        for i, r in enumerate(reqs):
-            if clock_s is None:  # wall-clock mode: service time only
-                lat_ms = dt_s * 1e3
+            try:
+                batch = dispatch(allow_partial)
+            except FaultError as e:
+                # unrecoverable storage fault under strict semantics: count it
+                # toward the breaker; once open, retry this dispatch degraded
+                # (partial allowed) instead of failing the serve loop — below
+                # the threshold, stay loud.
+                self._breaker_note(
+                    {"failed_shards": [getattr(e, "shard_id", -1)]})
+                if allow_partial or not self._breaker_open:
+                    raise
+                batch = dispatch(True)
             else:
-                lat_ms = (clock_s + dt_s - r.arrival_s) * 1e3  # queueing + service
-            if r.deadline_ms is not None and lat_ms > r.deadline_ms:
-                self.deadline_misses += 1
-            self._lat_ms[label].append(lat_ms)
-            results.append(SearchResult(
-                topk=TopK(scores[i], indices[i]),
-                plan=plan,
-                tier=batch.tier,
-                certified=bool(cert[i]) if cert is not None else True,
-                kernel_stats=batch.kernel_stats,
-                stats={"latency_ms": lat_ms, "batched": len(reqs),
-                       "mode": label, "deadline_ms": r.deadline_ms,
-                       "health": dict(health) if health is not None else {},
-                       "partial": partial},
-                rid=r.rid,
-            ))
-        self.served += len(reqs)
-        return results, dt_s
+                self._breaker_note(batch.stats.get("health"))
+            with TRACER.span("wait", worker.id):
+                # forces execution (device sync)
+                scores = np.asarray(batch.scores)[:b]
+                indices = np.asarray(batch.indices)[:b]
+            dt_s = time.perf_counter() - t0
+
+            plan = batch.plan
+            self._executors[label].add(plan.executor)
+            # dataset bytes one scan of this plan moved (the bandwidth account
+            # choose_tier optimizes), reported per tier AND per dispatch label
+            tier_key = batch.tier if batch.tier == "int8" else "f32"
+            self._bytes_scanned[tier_key] += batch.stats["bytes_scanned"]
+            self._tiers[label].add(batch.tier)
+            self._mode_bytes[label] += batch.stats["bytes_scanned"]
+            if batch.tier == "int8":
+                cert = np.asarray(batch.certified)[:b]
+                n_true = int(cert.sum())
+            else:
+                cert = None  # exact path: trivially certified
+                n_true = b
+            self._cert[label]["total"] += b
+            self._cert[label]["true"] += n_true
+            ks = batch.kernel_stats
+            if ks is not None and "prune_skip_rate" in ks:
+                # float() is a free sync here: results were materialized
+                # above
+                self._skip_rate_sum += float(ks["prune_skip_rate"])
+                self._skip_rate_n += 1
+            self._transfers += int(batch.stats.get("transfers", 0))
+            self._restarts += int(batch.stats.get("restarts", 0))
+            if "scan_ms" in batch.stats:  # streamed AND sharded int8 plans
+                # report the same scan/gather/rescore wall-time split — mesh
+                # dispatches aggregate here exactly like single-device ones
+                self._phase_n += 1
+                for key in self._phase_ms:
+                    self._phase_ms[key] += float(batch.stats.get(key, 0.0))
+            per_dev = batch.stats.get("bytes_per_device")
+            if per_dev is not None:  # sharded dispatch: per-device scan bytes
+                if len(per_dev) > len(self._device_bytes):
+                    self._device_bytes.extend(
+                        [0] * (len(per_dev) - len(self._device_bytes)))
+                for di, nbytes in enumerate(per_dev):
+                    self._device_bytes[di] += int(nbytes)
+            spec = batch.stats.get("speculation")
+            if spec is not None:
+                self._speculation["dispatches"] += 1
+                for key in ("rows_speculated", "rows_topped_up",
+                            "rows_wasted"):
+                    self._speculation[key] += int(spec.get(key, 0))
+            health = batch.stats.get("health")
+            if health is not None:
+                self._health_agg["retries"] += int(health.get("retries", 0))
+                for key in ("failed_shards", "degraded", "slow_shards"):
+                    self._health_agg[key].update(health.get(key, ()))
+            partial = bool(batch.stats.get("partial", False))
+            if self._last_mode is not None and label != self._last_mode:
+                self._switches += 1
+            self._last_mode = label
+            ema = self._ema_s[label]
+            self._ema_s[label] = (
+                dt_s if ema is None else 0.7 * ema + 0.3 * dt_s)
+            self._svc_s[label] += dt_s
+            self._count[label] += len(reqs)
+
+            results = []
+            for i, r in enumerate(reqs):
+                if clock_s is None:  # wall-clock mode: service time only
+                    lat_ms = dt_s * 1e3
+                else:
+                    # queueing + service
+                    lat_ms = (clock_s + dt_s - r.arrival_s) * 1e3
+                if r.deadline_ms is not None and lat_ms > r.deadline_ms:
+                    self.deadline_misses += 1
+                self._lat_ms[label].append(lat_ms)
+                results.append(SearchResult(
+                    topk=TopK(scores[i], indices[i]),
+                    plan=plan,
+                    tier=batch.tier,
+                    certified=bool(cert[i]) if cert is not None else True,
+                    kernel_stats=batch.kernel_stats,
+                    stats={"latency_ms": lat_ms, "batched": len(reqs),
+                           "mode": label, "deadline_ms": r.deadline_ms,
+                           "health": (dict(health) if health is not None
+                                      else {}),
+                           "partial": partial},
+                    rid=r.rid,
+                ))
+            self.served += len(reqs)
+            return results, dt_s
 
     def _shed_result(self, r: SearchRequest, clock_s: float) -> SearchResult:
         """An expired request's answer: empty top-k (inf scores, -1 ids),
