@@ -178,7 +178,13 @@ async def read_http_request(
 def jsonable(obj: Any) -> Any:
     """Recursively convert stats payloads to strict JSON: numpy scalars to
     Python numbers, sets/tuples to sorted lists/lists, non-finite floats
-    to None (strict JSON has no Infinity/NaN)."""
+    to None (strict JSON has no Infinity/NaN).
+
+    A numeric array converts in one vectorised step per row (``tolist``,
+    then ``None`` where ``np.isfinite`` fails), not one call per element:
+    a k=1024 answer is 2,048 numbers. Other dtypes, longdouble included,
+    go element by element.
+    """
     if isinstance(obj, dict):
         return {str(k): jsonable(v) for k, v in obj.items()}
     if isinstance(obj, (list, tuple)):
@@ -186,7 +192,16 @@ def jsonable(obj: Any) -> Any:
     if isinstance(obj, (set, frozenset)):
         return sorted(jsonable(v) for v in obj)
     if isinstance(obj, np.ndarray):
-        return [jsonable(v) for v in obj.tolist()]
+        # longdouble (itemsize 12/16) tolist()s to numpy scalars, not floats
+        if obj.dtype.kind not in "biuf" or obj.dtype.itemsize > 8:
+            return [jsonable(v) for v in obj.tolist()]
+        if obj.ndim != 1:
+            return [jsonable(row) for row in obj]
+        out = obj.tolist()
+        if obj.dtype.kind == "f":
+            for i in np.flatnonzero(~np.isfinite(obj)):
+                out[i] = None
+        return out
     if isinstance(obj, (bool, np.bool_)):
         return bool(obj)
     if isinstance(obj, (int, np.integer)):
@@ -404,8 +419,11 @@ def parse_search_request(
 def encode_result(result: SearchResult) -> dict:
     """One served :class:`SearchResult` -> response body dict.
 
-    Shed results (`stats["mode"] == "shed"`) keep their documented envelope
-    — empty top-k, ``shed: true`` — rather than pretending to be answers.
+    ``scores`` and ``indices`` stay numpy arrays: the body is strict JSON
+    once :func:`http_response` has encoded it, in its one :func:`jsonable`
+    pass. Shed results (`stats["mode"] == "shed"`) keep their documented
+    envelope — empty top-k, ``shed: true`` — rather than pretending to be
+    answers.
     """
     stats = dict(result.stats)
     shed = bool(stats.get("shed", False))
@@ -430,4 +448,4 @@ def encode_result(result: SearchResult) -> dict:
         body["scores"] = np.asarray(result.scores)
         body["indices"] = np.asarray(result.indices)
         body["certified"] = result.certified
-    return jsonable(body)
+    return body
